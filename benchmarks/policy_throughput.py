@@ -79,12 +79,7 @@ def bench_rows(fast: bool = False,
             brng = np.random.default_rng(1)
             run = lambda: policy.select_batch(  # noqa: E731
                 store, budgets, brng, backend=backend)
-            try:
-                run()  # warm-up (jit compile for the jax path)
-            except Exception as e:  # pragma: no cover - missing accelerator
-                rows.append((f"policy_throughput/{backend}/batch_{B}", 0.0,
-                             f"SKIP:{type(e).__name__}"))
-                continue
+            run()  # warm-up (jit compile for the jax path)
             selps = _best_rate(run, B)
             rows.append((f"policy_throughput/{backend}/batch_{B}",
                          1e6 / selps,

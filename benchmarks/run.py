@@ -26,6 +26,9 @@ def main() -> None:
     if args.smoke:
         args.fast = True
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import drift_resilience as dr
     from benchmarks import elastic_controllers as ec
     from benchmarks import engine_throughput as et

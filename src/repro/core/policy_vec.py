@@ -34,7 +34,6 @@ set ``numpy`` to force the reference path, ``jax`` to force the kernel.
 """
 from __future__ import annotations
 
-import functools
 import os
 from typing import List, Optional, Sequence, Tuple, Union
 
@@ -74,11 +73,9 @@ def _resolve_backend(backend: Optional[str], n_batch: int) -> str:
         # The fused device pipeline (stages 1–3 under one jit, Pallas
         # stage 3 on TPU / plain XLA elsewhere) beats numpy above the
         # measured crossover on CPU as well as TPU, so auto engages it
-        # wherever jax can compile — no interpret-mode Pallas is left on
-        # this path (see BENCH_policy_throughput.json).
-        if n_batch >= JAX_MIN_BATCH and _jax_available():
-            return "jax"
-        return "numpy"
+        # at every backend — no interpret-mode Pallas is left on this
+        # path (see BENCH_policy_throughput.json).
+        return "jax" if n_batch >= JAX_MIN_BATCH else "numpy"
     return backend
 
 
@@ -89,16 +86,6 @@ def resolve_backend(backend: Optional[str], n_batch: int) -> str:
     ``kernels.policy_select.charged_select`` under the same policy as
     the uncharged fused pipeline."""
     return _resolve_backend(backend, n_batch)
-
-
-@functools.lru_cache(maxsize=1)
-def _jax_available() -> bool:
-    try:
-        import jax
-        jax.default_backend()
-        return True
-    except Exception:  # pragma: no cover - jax is baked into the container
-        return False
 
 
 # ----------------------------------------------------------------------

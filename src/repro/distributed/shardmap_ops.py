@@ -15,7 +15,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ops
@@ -41,8 +40,8 @@ def sharded_flash_attention(q, k, v, mesh: Mesh, *, causal: bool = True,
     spec = P(dp or None, hax, None, None)
 
     fn = partial(ops.flash_attention, causal=causal, window=window)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def sharded_decode_attention(q, k, v, pos, mesh: Mesh, *, window: int = 0):
@@ -54,9 +53,9 @@ def sharded_decode_attention(q, k, v, pos, mesh: Mesh, *, window: int = 0):
     spec_pos = P(dp or None)
 
     fn = partial(ops.decode_attention, window=window)
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(spec_q, spec_kv, spec_kv, spec_pos),
-                     out_specs=spec_q, check_rep=False)(q, k, v, pos)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(spec_q, spec_kv, spec_kv, spec_pos),
+                         out_specs=spec_q, check_vma=False)(q, k, v, pos)
 
 
 def sharded_ssd_scan(x, dt, A, B_, C_, mesh: Mesh, *, chunk: int = 128):
@@ -66,14 +65,14 @@ def sharded_ssd_scan(x, dt, A, B_, C_, mesh: Mesh, *, chunk: int = 128):
     dp = _data_axes(mesh)
     hax = _head_axis(mesh, x.shape[1], B_.shape[1])
     fn = partial(ops.ssd_scan, chunk=chunk)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(dp or None, hax, None, None),
                   P(dp or None, hax, None),
                   P(hax),
                   P(dp or None, hax, None, None),
                   P(dp or None, hax, None, None)),
-        out_specs=P(dp or None, hax, None, None), check_rep=False)(
+        out_specs=P(dp or None, hax, None, None), check_vma=False)(
             x, dt, A, B_, C_)
 
 
@@ -97,9 +96,9 @@ def sharded_fleet_select(mu, sig, acc, rank, t_u, t_l, keys, mesh: Mesh,
     with axis_rules({"cell": ax}, mesh):
         spec = logical_to_spec(("cell", None), shape=t_u.shape, mesh=mesh)
     body = jax.vmap(partial(fleet_select_body, gamma=gamma))
-    return shard_map(body, mesh=mesh, in_specs=(spec,) * 7,
-                     out_specs=spec, check_rep=False)(
-                         mu, sig, acc, rank, t_u, t_l, keys)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 7,
+                         out_specs=spec, check_vma=False)(
+                             mu, sig, acc, rank, t_u, t_l, keys)
 
 
 def sharded_rglru_scan(a, b, mesh: Mesh, *, block_s: int = 256):
@@ -108,5 +107,5 @@ def sharded_rglru_scan(a, b, mesh: Mesh, *, block_s: int = 256):
     tp = "model" if "model" in mesh.shape and a.shape[2] % mesh.shape["model"] == 0 else None
     spec = P(dp or None, None, tp)
     fn = partial(ops.rglru_scan, block_s=block_s)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                     check_rep=False)(a, b)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)(a, b)

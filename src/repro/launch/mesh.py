@@ -6,6 +6,7 @@ module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,12 +14,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips across DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for debug runs (e.g. (2, 4) on 8 fake devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh for debug runs (e.g. (2, 4) on 8 fake devices).
+
+    Axes are ``Auto``: the model code places arrays with
+    ``with_sharding_constraint`` and lets GSPMD propagate, which
+    ``Explicit`` axes (``jax.make_mesh``'s default) refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axis(mesh, name: str) -> int:

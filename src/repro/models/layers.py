@@ -1,6 +1,7 @@
 """Shared layer primitives: norms, RoPE, MLPs, parameter templates."""
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -32,29 +33,35 @@ def spec_map(fn, tree, *rest):
     return jax.tree.map(fn, tree, *rest, is_leaf=is_spec)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_leaf(spec: ParamSpec, k, dtype):
+    """One leaf, drawn under jit: the float32 draw, its scale and the cast
+    to ``dtype`` fuse, so only the ``dtype`` leaf is ever allocated on the
+    device (a published-width stacked FFN leaf in float32 would not fit
+    beside the rest of a pool)."""
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, dtype)
+    if spec.init == "ones":
+        return jnp.ones(spec.shape, dtype)
+    if spec.init == "rglru_lambda":
+        # Λ init so that a = sigmoid(Λ)^c spreads over (0.9, 0.999)
+        u = jax.random.uniform(k, spec.shape, jnp.float32, 0.9, 0.999)
+        lam = jnp.log(u ** (-2.0) - 1.0) * 0.5  # inverse of the a(Λ) map
+        return lam.astype(dtype)
+    fan_in = spec.shape[spec.scale_dim] if spec.shape else 1
+    if spec.init == "normal_out":  # residual-out projection: extra-scaled
+        std = 0.02 / jnp.sqrt(2.0)
+    else:
+        std = 1.0 / jnp.sqrt(max(1, fan_in))
+    return (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(dtype)
+
+
 def materialize(template, key, dtype):
     """Initialize real parameters from a template tree."""
     leaves, treedef = jax.tree.flatten(template, is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
-
-    def init_one(spec: ParamSpec, k):
-        if spec.init == "zeros":
-            return jnp.zeros(spec.shape, dtype)
-        if spec.init == "ones":
-            return jnp.ones(spec.shape, dtype)
-        if spec.init == "rglru_lambda":
-            # Λ init so that a = sigmoid(Λ)^c spreads over (0.9, 0.999)
-            u = jax.random.uniform(k, spec.shape, jnp.float32, 0.9, 0.999)
-            lam = jnp.log(u ** (-2.0) - 1.0) * 0.5  # inverse of the a(Λ) map
-            return lam.astype(dtype)
-        fan_in = spec.shape[spec.scale_dim] if spec.shape else 1
-        if spec.init == "normal_out":  # residual-out projection: extra-scaled
-            std = 0.02 / jnp.sqrt(2.0)
-        else:
-            std = 1.0 / jnp.sqrt(max(1, fan_in))
-        return (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(dtype)
-
-    return treedef.unflatten([init_one(s, k) for s, k in zip(leaves, keys)])
+    return treedef.unflatten([_init_leaf(s, k, jnp.dtype(dtype))
+                              for s, k in zip(leaves, keys)])
 
 
 def abstract(template, dtype):

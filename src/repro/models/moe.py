@@ -69,7 +69,8 @@ def moe_ffn(params, x, cfg: ModelConfig):
     keep = pos < C
 
     gate_kept = top_gates * keep  # dropped entries contribute nothing
-    pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[..., None]
+    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), C,
+                            dtype=jnp.float32) * keep[..., None]
     # combine[G,g,E,C] = Σ_k gate · 1[expert] · 1[slot]
     combine = jnp.einsum("GgKE,GgKC->GgEC", one_hot_all * gate_kept[..., None], pos_oh)
     dispatch = (combine > 0).astype(x.dtype)

@@ -556,10 +556,7 @@ class Scenario:
         round-trip like every other field."""
         p = str(path)
         if p.endswith(".toml"):
-            try:
-                import tomllib          # 3.11+
-            except ImportError:         # pragma: no cover - env-dependent
-                import tomli as tomllib
+            import tomllib
             with open(p, "rb") as f:
                 return cls.from_dict(tomllib.load(f))
         import json

@@ -1,13 +1,17 @@
 """Live model pool: JAX-served variants exposing accuracy/latency
 trade-offs (the LLM analogue of the paper's CNN zoo).
 
-Each variant owns compiled prefill/decode functions; ``scaled_family``
-builds a pool from one architecture at several widths/depths — e.g.
-qwen2-family at 0.25×/0.5×/1× — exactly the MobileNet-vs-Inception
-spectrum ModiPick exploits.
+Each variant owns compiled prefill/decode functions.
+``Variant.from_config`` builds one member from a registry config at its
+published widths in the config's dtype (the served pool,
+``launch.serve.build_pool``); ``scaled_family`` builds a reduced-width
+float32 pool from one architecture at several widths — the
+MobileNet-vs-Inception spectrum ModiPick exploits, at a size the CPU
+tests can run.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
@@ -18,6 +22,18 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import api, model as M
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "cache_len"))
+def prefill_step(cfg: ModelConfig, params, tokens, *, cache_len: int):
+    """A pool member's compiled prefill: (cache, last-token logits)."""
+    return M.prefill(cfg, params, {"tokens": tokens}, cache_len)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def decode_step(cfg: ModelConfig, params, cache, tok, pos):
+    """A pool member's compiled decode step: (logits, new cache)."""
+    return M.decode_step(cfg, params, cache, tok, pos)
 
 
 @dataclass
@@ -38,20 +54,20 @@ class Variant:
         in-flight work — so take the max rather than the sum."""
         return max(self.inflight * max(profile.mu, 0.0), profile.queue_mu)
 
+    @classmethod
+    def from_config(cls, cfg: ModelConfig, key, *,
+                    cache_len: int = 128) -> "Variant":
+        """A pool member at the config's own widths and dtype (bf16 for
+        every registry config); its quality is the config's declared
+        ``quality``."""
+        return cls(name=cfg.name, cfg=cfg, quality=cfg.quality,
+                   cache_len=cache_len).build(key, jnp.dtype(cfg.dtype))
+
     def build(self, key, dtype=jnp.float32):
         self.params = M.init_params(self.cfg, key, dtype)
-        cache_len = self.cache_len
-
-        @jax.jit
-        def prefill_fn(params, tokens):
-            return M.prefill(self.cfg, params, {"tokens": tokens}, cache_len)
-
-        @jax.jit
-        def decode_fn(params, cache, tok, pos):
-            return M.decode_step(self.cfg, params, cache, tok, pos)
-
-        self.prefill_fn = prefill_fn
-        self.decode_fn = decode_fn
+        self.prefill_fn = functools.partial(prefill_step, self.cfg,
+                                            cache_len=self.cache_len)
+        self.decode_fn = functools.partial(decode_step, self.cfg)
         return self
 
     def run(self, tokens: np.ndarray, n_decode: int = 4) -> float:

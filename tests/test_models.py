@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import MoEConfig, SSMConfig
 from repro.configs.registry import get_config

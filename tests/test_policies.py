@@ -1,10 +1,9 @@
-"""Property tests for the ModiPick selection policies (seeded sweeps via
-the conftest shim; uses real hypothesis when available)."""
+"""Property tests for the ModiPick selection policies (Hypothesis)."""
 import math
 
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.policy import (DynamicGreedy, ModiPick, PureRandom,
                                RelatedAccurate, RelatedRandom, StaticGreedy,

@@ -1,5 +1,5 @@
-"""Vectorized policy engine: scalar↔batched equivalence (property sweeps
-via the conftest shim), ProfileTable snapshot semantics, the seeded
+"""Vectorized policy engine: scalar↔batched equivalence (Hypothesis
+property sweeps), ProfileTable snapshot semantics, the seeded
 end-to-end goldens pinning the ProfileTable rewire, StaticGreedy
 re-freeze, the rejected-inclusive utilization horizon, and the
 benchmark-harness smoke."""
@@ -11,7 +11,7 @@ from pathlib import Path
 
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import policy_vec
 from repro.core.netmodel import NetworkModel

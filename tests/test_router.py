@@ -13,7 +13,7 @@ from typing import Callable
 
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import repro.sim.engine as engine_mod
 from repro.core import policy_vec

@@ -71,10 +71,7 @@ def test_from_file_round_trip_and_error_paths(tmp_path):
     with pytest.raises(json.JSONDecodeError):
         Scenario.from_file(bad_json)
 
-    try:
-        import tomllib
-    except ImportError:
-        import tomli as tomllib
+    import tomllib
     bad_toml = tmp_path / "bad.toml"
     bad_toml.write_text("name = ", encoding="utf-8")
     with pytest.raises(tomllib.TOMLDecodeError):

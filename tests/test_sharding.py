@@ -6,12 +6,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import compression as comp
 from repro.distributed import hlo as hlo_mod
 from repro.distributed.sharding import logical_to_spec
+from repro.launch.mesh import make_mesh
 
 
 class FakeMesh:
@@ -117,12 +118,12 @@ def test_error_feedback_reduces_bias():
 def test_compressed_psum_under_shard_map():
     if jax.device_count() < 2:
         pytest.skip("needs >1 device")
-    from jax.experimental.shard_map import shard_map
-    mesh = jax.make_mesh((jax.device_count(),), ("d",))
+    mesh = make_mesh((jax.device_count(),), ("d",))
     x = jnp.arange(jax.device_count() * 4, dtype=jnp.float32).reshape(
         jax.device_count(), 4)
-    f = shard_map(lambda v: comp.compressed_psum(v[0], "d")[None],
-                  mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
+    f = jax.shard_map(lambda v: comp.compressed_psum(v[0], "d")[None],
+                      mesh=mesh, in_specs=P("d", None),
+                      out_specs=P("d", None))
     out = f(x)
     expect = x.mean(axis=0)
     np.testing.assert_allclose(np.asarray(out)[0], np.asarray(expect),

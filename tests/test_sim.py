@@ -2,7 +2,7 @@
 closed-loop equivalence, queue-aware budgets, and load behaviour."""
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.netmodel import NetworkModel
 from repro.core.policy import (DynamicGreedy, ModiPick, StaticGreedy,
