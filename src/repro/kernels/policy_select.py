@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.obs import span
+
 EPS = 1e-9
 LANES = 128
 # Padded-lane sentinels: a fake model this slow can never be eligible,
@@ -558,33 +560,36 @@ def charged_select(pool: DevicePool, t_u, t_l, state, *,
     bpad = _bucket(B, block_b)
     f32 = jnp.float32
 
-    cand_mask = np.zeros((npad, R), dtype=bool)
-    for m, c in enumerate(state.cand):
-        cand_mask[m, np.asarray(c)] = True
-    mu_charge = np.zeros(npad, np.float32)
-    mu_charge[:n] = np.asarray(state.mu, np.float64)[:n]
+    with span("router.select.pack"):
+        cand_mask = np.zeros((npad, R), dtype=bool)
+        for m, c in enumerate(state.cand):
+            cand_mask[m, np.asarray(c)] = True
+        mu_charge = np.zeros(npad, np.float32)
+        mu_charge[:n] = np.asarray(state.mu, np.float64)[:n]
 
-    lim = np.full(bpad, -np.inf, np.float32)
-    if adm_limit is None:
-        lim[:B] = np.inf
-    else:
-        lim[:B] = np.asarray(adm_limit, np.float32)
-    r01 = jax.random.uniform(jax.random.PRNGKey(seed), (bpad,),
-                             dtype=f32)
+        lim = np.full(bpad, -np.inf, np.float32)
+        if adm_limit is None:
+            lim[:B] = np.inf
+        else:
+            lim[:B] = np.asarray(adm_limit, np.float32)
+        args = (pool.mu, pool.sigma, pool.acc, pool.rank,
+                jnp.asarray(mu_charge), jnp.asarray(cand_mask),
+                jnp.asarray(state.speed, f32),
+                jnp.asarray(state.rep_wait, f32),
+                jnp.asarray(_pad_batch(t_u, bpad)),
+                jnp.asarray(_pad_batch(t_l, bpad)))
+        lim_dev = jnp.asarray(lim)
+    with span("router.select.draw"):
+        r01 = jax.random.uniform(jax.random.PRNGKey(seed), (bpad,),
+                                 dtype=f32)
 
     fn = _charged_jit(npad, float(gamma), float(adm_slack),
                       bool(adm_include_mu), pool.fastest)
-    picks, admitted, has_base, rep, w_chosen = fn(
-        pool.mu, pool.sigma, pool.acc, pool.rank,
-        jnp.asarray(mu_charge), jnp.asarray(cand_mask),
-        jnp.asarray(state.speed, f32),
-        jnp.asarray(state.rep_wait, f32),
-        jnp.asarray(_pad_batch(t_u, bpad)),
-        jnp.asarray(_pad_batch(t_l, bpad)),
-        r01, jnp.asarray(lim))
-    return (np.asarray(picks)[:B], np.asarray(admitted)[:B],
-            np.asarray(has_base)[:B], np.asarray(rep)[:B],
-            np.asarray(w_chosen, np.float64)[:B])
+    with span("router.select.readback"):
+        picks, admitted, has_base, rep, w_chosen = fn(*args, r01, lim_dev)
+        return (np.asarray(picks)[:B], np.asarray(admitted)[:B],
+                np.asarray(has_base)[:B], np.asarray(rep)[:B],
+                np.asarray(w_chosen, np.float64)[:B])
 
 
 def masks_device(pool: DevicePool, t_u, t_l):

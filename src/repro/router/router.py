@@ -64,6 +64,7 @@ import numpy as np
 from repro.core import policy_vec
 from repro.core.policy import ModiPick, Policy, budget
 from repro.core.profiles import ProfileStore
+from repro.obs import span
 
 from repro.router.admission import (AdmissionController, AdmitAll, DepthFn,
                                     SlaAwareAdmission)
@@ -109,6 +110,8 @@ class Router:
         self.n_shed = 0
         self.n_fallback = 0
         self.n_batches = 0
+        # Batches that rode the device charged scan (the jax backend).
+        self.n_scan_batches = 0
         # Recovery path (router.retry): re-route requests and outcomes.
         self.n_retries = 0
         self.n_retry_routed = 0
@@ -206,59 +209,60 @@ class Router:
         needs a request record to judge (``_requests`` lets the object
         adapter pass the real ones through).
         """
-        t_sla = np.asarray(t_sla_ms, dtype=np.float64)
-        t_input = np.asarray(t_input_ms, dtype=np.float64)
-        B = len(t_sla)
-        tab = self.store.table()
-        want_traces = _requests is not None
-        res = BatchDecisions.empty(B, tab.names, traces=want_traces)
-        if B == 0:
-            return res
+        with span("router.route_batch"):
+            t_sla = np.asarray(t_sla_ms, dtype=np.float64)
+            t_input = np.asarray(t_input_ms, dtype=np.float64)
+            B = len(t_sla)
+            tab = self.store.table()
+            want_traces = _requests is not None
+            res = BatchDecisions.empty(B, tab.names, traces=want_traces)
+            if B == 0:
+                return res
 
-        # -- resolve the wait telemetry once per batch ------------------
-        needs_waits = self.queue_aware or self.admission.needs_w_queue
-        state: Optional[ChargedWaits] = None
-        waits: Optional[Dict[str, float]] = None
-        if needs_waits:
-            if charged is not None:
-                state = charged
-            elif w_queue_map is not None:
-                waits = w_queue_map
+            # -- resolve the wait telemetry once per batch ------------------
+            needs_waits = self.queue_aware or self.admission.needs_w_queue
+            state: Optional[ChargedWaits] = None
+            waits: Optional[Dict[str, float]] = None
+            if needs_waits:
+                if charged is not None:
+                    state = charged
+                elif w_queue_map is not None:
+                    waits = w_queue_map
+                else:
+                    # No injected snapshot: query per model, falling back to
+                    # the store's own EWMA queue telemetry (0 until the
+                    # first observation) absent an estimator.
+                    fn = w_queue_fn or self.store.queue_wait
+                    waits = {n: max(0.0, float(fn(n)))
+                             for n in self.store.profiles}
+
+            if B == 1:
+                self._route_singleton(
+                    res, float(t_sla[0]), float(t_input[0]), rng, state, waits,
+                    depth_fn,
+                    _requests[0] if _requests is not None else None,
+                    sla_class[0] if sla_class is not None else None)
+            elif charge and needs_waits:
+                if state is None:
+                    # Snapshot-only telemetry: charge at model granularity
+                    # (each model its own queue — the per-model-endpoint
+                    # topology) so the fix does not require a replica pool.
+                    state = ChargedWaits.per_model(
+                        tab.names, [waits[n] for n in tab.names], tab.mu)
+                self._route_charged(res, t_sla, t_input, rng, state, depth_fn,
+                                    _requests, sla_class)
             else:
-                # No injected snapshot: query per model, falling back to
-                # the store's own EWMA queue telemetry (0 until the
-                # first observation) absent an estimator.
-                fn = w_queue_fn or self.store.queue_wait
-                waits = {n: max(0.0, float(fn(n)))
-                         for n in self.store.profiles}
+                self._route_snapshot(res, t_sla, t_input, rng,
+                                     state.as_map() if state is not None
+                                     else waits,
+                                     depth_fn, _requests, sla_class)
 
-        if B == 1:
-            self._route_singleton(
-                res, float(t_sla[0]), float(t_input[0]), rng, state, waits,
-                depth_fn,
-                _requests[0] if _requests is not None else None,
-                sla_class[0] if sla_class is not None else None)
-        elif charge and needs_waits:
-            if state is None:
-                # Snapshot-only telemetry: charge at model granularity
-                # (each model its own queue — the per-model-endpoint
-                # topology) so the fix does not require a replica pool.
-                state = ChargedWaits.per_model(
-                    tab.names, [waits[n] for n in tab.names], tab.mu)
-            self._route_charged(res, t_sla, t_input, rng, state, depth_fn,
-                                _requests, sla_class)
-        else:
-            self._route_snapshot(res, t_sla, t_input, rng,
-                                 state.as_map() if state is not None
-                                 else waits,
-                                 depth_fn, _requests, sla_class)
-
-        self.n_batches += 1
-        self.n_routed += B
-        n_admitted = int(res.admitted.sum())
-        self.n_admitted += n_admitted
-        self.n_shed += B - n_admitted
-        return res
+            self.n_batches += 1
+            self.n_routed += B
+            n_admitted = int(res.admitted.sum())
+            self.n_admitted += n_admitted
+            self.n_shed += B - n_admitted
+            return res
 
     # ------------------------------------------------------------------
     def _admission_request(self, requests, sla_class, i,
@@ -432,47 +436,48 @@ class Router:
         if self._use_charged_scan(B):
             self._route_charged_jax(res, budgets, rng, state)
             return
-        names = tab.names
-        index = tab.index
-        select = (self.policy.select_traced if self.trace_detail
-                  else self.policy.select_lean)
-        check_admission = not self._admits_all
-        for i in range(B):
-            wq = state.model_waits()
-            # The live charged snapshot this request is judged against —
-            # same keys, same clamped floats a singleton route would
-            # build, but including every charge so far.
-            waits = dict(zip(names, wq.tolist()))
-            if check_admission:
-                req = self._admission_request(requests, sla_class, i,
-                                              float(t_sla[i]),
-                                              float(t_input[i]))
-                ok, reason = self.admission.admit(
-                    req, float(budgets[i]), tab, waits.__getitem__,
-                    depth_fn)
-                if not ok:
-                    self._shed(res, i, reason, float(wq.min()))
-                    continue
-            sel_store = (shifted_store(self.store, waits.__getitem__,
-                                       shifts=waits)
-                         if self.queue_aware else self.store)
-            trace = select(sel_store, float(budgets[i]), rng)
-            self.store.mark_selected(trace.chosen)
-            mid = index[trace.chosen]
-            res.model_idx[i] = mid
-            res.admitted[i] = True
-            res.fallback[i] = trace.fallback
-            res.w_queue_ms[i] = float(wq[mid])
-            if trace.fallback:
-                self.n_fallback += 1
-            if res.traces is not None:
-                res.traces[i] = trace
-            # Charge the pick before the next request is judged; the
-            # returned replica is where a placement-consistent caller
-            # should enqueue it.
-            ridx = state.charge(mid)
-            if not state.pseudo:
-                res.replica_idx[i] = ridx
+        with span("router.charged_loop"):
+            names = tab.names
+            index = tab.index
+            select = (self.policy.select_traced if self.trace_detail
+                      else self.policy.select_lean)
+            check_admission = not self._admits_all
+            for i in range(B):
+                wq = state.model_waits()
+                # The live charged snapshot this request is judged against —
+                # same keys, same clamped floats a singleton route would
+                # build, but including every charge so far.
+                waits = dict(zip(names, wq.tolist()))
+                if check_admission:
+                    req = self._admission_request(requests, sla_class, i,
+                                                  float(t_sla[i]),
+                                                  float(t_input[i]))
+                    ok, reason = self.admission.admit(
+                        req, float(budgets[i]), tab, waits.__getitem__,
+                        depth_fn)
+                    if not ok:
+                        self._shed(res, i, reason, float(wq.min()))
+                        continue
+                sel_store = (shifted_store(self.store, waits.__getitem__,
+                                           shifts=waits)
+                             if self.queue_aware else self.store)
+                trace = select(sel_store, float(budgets[i]), rng)
+                self.store.mark_selected(trace.chosen)
+                mid = index[trace.chosen]
+                res.model_idx[i] = mid
+                res.admitted[i] = True
+                res.fallback[i] = trace.fallback
+                res.w_queue_ms[i] = float(wq[mid])
+                if trace.fallback:
+                    self.n_fallback += 1
+                if res.traces is not None:
+                    res.traces[i] = trace
+                # Charge the pick before the next request is judged; the
+                # returned replica is where a placement-consistent caller
+                # should enqueue it.
+                ridx = state.charge(mid)
+                if not state.pseudo:
+                    res.replica_idx[i] = ridx
 
     # ------------------------------------------------------------------
     # premodel surface (class-conditional batch routing)
@@ -627,22 +632,24 @@ class Router:
             adm_limit=adm_limit, adm_slack=slack,
             adm_include_mu=include_mu,
             seed=int(rng.integers(np.iinfo(np.int64).max)))
+        self.n_scan_batches += 1
         picks, admitted, has_base, replica, w_chosen = out
         names = tab.names
-        for i in range(len(budgets)):
-            if not admitted[i]:
-                self._shed(res, i,
-                           "W_queue exceeds the remaining budget for "
-                           "every model", float(w_chosen[i]))
-                continue
-            mid = int(picks[i])
-            self.store.mark_selected(names[mid])
-            res.model_idx[i] = mid
-            res.admitted[i] = True
-            res.fallback[i] = not has_base[i]
-            res.w_queue_ms[i] = float(w_chosen[i])
-            if not state.pseudo:
-                res.replica_idx[i] = int(replica[i])
+        with span("router.apply"):
+            for i in range(len(budgets)):
+                if not admitted[i]:
+                    self._shed(res, i,
+                               "W_queue exceeds the remaining budget for "
+                               "every model", float(w_chosen[i]))
+                    continue
+                mid = int(picks[i])
+                self.store.mark_selected(names[mid])
+                res.model_idx[i] = mid
+                res.admitted[i] = True
+                res.fallback[i] = not has_base[i]
+                res.w_queue_ms[i] = float(w_chosen[i])
+                if not state.pseudo:
+                    res.replica_idx[i] = int(replica[i])
         self.n_fallback += int((res.admitted & res.fallback).sum())
 
     # ------------------------------------------------------------------
@@ -718,6 +725,7 @@ class Router:
         self.n_shed = 0
         self.n_fallback = 0
         self.n_batches = 0
+        self.n_scan_batches = 0
         self.n_retries = 0
         self.n_retry_routed = 0
         self.n_retry_exhausted = 0
@@ -751,6 +759,7 @@ class Router:
             "n_shed": self.n_shed,
             "n_fallback": self.n_fallback,
             "n_batches": self.n_batches,
+            "n_scan_batches": self.n_scan_batches,
             "n_retries": self.n_retries,
             "n_retry_routed": self.n_retry_routed,
             "n_retry_exhausted": self.n_retry_exhausted,

@@ -19,6 +19,7 @@ the Router):
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
@@ -27,6 +28,7 @@ import numpy as np
 from repro.core.netmodel import NetworkModel
 from repro.core.policy import Policy
 from repro.core.profiles import ModelProfile, ProfileStore
+from repro.obs import span
 from repro.router import AdmissionController, InferenceRequest, Router
 from repro.serving.pool import Variant
 
@@ -44,6 +46,8 @@ class RequestResult:
     w_queue_ms: float = 0.0     # queue-wait estimate charged at selection
     admitted: bool = True       # False: shed by router-side admission
     reject_reason: str = ""
+    tokens_served: int = 0      # n_decode + 1 when served, 0 when shed
+    t_queue_ms: float = 0.0     # arrival -> execute start (0: not given)
 
 
 @dataclass
@@ -108,47 +112,63 @@ class PoolExecutor:
                 self.store.observe(v.name, ms)
 
     def execute(self, tokens: np.ndarray, t_sla: float,
-                n_decode: int = 2) -> RequestResult:
-        t_input = float(self.network.sample(self.rng, 1)[0])
-        request = InferenceRequest(rid=len(self.results), t_sla_ms=t_sla,
-                                   t_input_ms=t_input)
-        dec = self.router.route(request, self.rng, w_queue_fn=self.w_queue)
-        if not dec.admitted:
-            # Shed before any model ran: the downlink never happens, but
-            # the uplink was already spent — charge it and score a miss.
-            res = RequestResult(
-                variant="", t_input_ms=t_input, t_infer_ms=0.0,
-                t_e2e_ms=t_input, t_sla_ms=t_sla, met_sla=False,
-                quality=0.0, w_queue_ms=dec.budget.w_queue_ms,
-                admitted=False, reject_reason=dec.reject_reason)
-            self.results.append(res)
-            return res
-        name = dec.variant
-        v = self.by_name[name]
-        v.inflight = getattr(v, "inflight", 0) + 1
-        try:
-            t_infer = v.run(tokens, n_decode)
-        finally:
-            v.inflight -= 1
-        hedged = False
-        prof = self.store[name]
-        if self.hedging and prof.n_obs > 3 and \
-                t_infer > prof.mu + self.hedge_k * prof.sigma:
-            # re-issue on the fastest variant; overlap from detection point
-            fast = min(self.store.profiles.values(), key=lambda p: p.mu)
-            if fast.name != name:
-                detect = prof.mu + self.hedge_k * prof.sigma
-                t2 = self.by_name[fast.name].run(tokens, n_decode)
-                t_infer = min(t_infer, detect + t2)
-                hedged = True
-        self.store.observe(name, t_infer)
-        e2e = 2.0 * t_input + t_infer
-        res = RequestResult(
-            variant=name, t_input_ms=t_input, t_infer_ms=t_infer,
-            t_e2e_ms=e2e, t_sla_ms=t_sla, met_sla=e2e <= t_sla,
-            quality=v.quality, hedged=hedged,
-            w_queue_ms=dec.budget.w_queue_ms)
-        self.results.append(res)
+                n_decode: int = 2,
+                arrival_s: Optional[float] = None) -> RequestResult:
+        """Serve one request.  ``arrival_s`` is its arrival at the server
+        on the ``time.perf_counter()`` clock: where given, the wait from
+        it to this call is recorded as ``t_queue_ms`` and counted in the
+        end-to-end time and the SLA verdict."""
+        t_queue = (0.0 if arrival_s is None
+                   else (time.perf_counter() - arrival_s) * 1e3)
+        with span("pool.exec"):
+            t_input = float(self.network.sample(self.rng, 1)[0])
+            request = InferenceRequest(rid=len(self.results), t_sla_ms=t_sla,
+                                       t_input_ms=t_input)
+            with span("pool.exec.route"):
+                dec = self.router.route(request, self.rng,
+                                        w_queue_fn=self.w_queue)
+            if not dec.admitted:
+                # Shed before any model ran: the downlink never happens,
+                # but the uplink was already spent — charge it and score
+                # a miss.
+                res = RequestResult(
+                    variant="", t_input_ms=t_input, t_infer_ms=0.0,
+                    t_e2e_ms=t_input + t_queue, t_sla_ms=t_sla,
+                    met_sla=False, quality=0.0,
+                    w_queue_ms=dec.budget.w_queue_ms, admitted=False,
+                    reject_reason=dec.reject_reason, t_queue_ms=t_queue)
+                self.results.append(res)
+                return res
+            name = dec.variant
+            v = self.by_name[name]
+            v.inflight = getattr(v, "inflight", 0) + 1
+            try:
+                t_infer = v.run(tokens, n_decode)
+            finally:
+                v.inflight -= 1
+            with span("pool.exec.observe"):
+                hedged = False
+                prof = self.store[name]
+                if self.hedging and prof.n_obs > 3 and \
+                        t_infer > prof.mu + self.hedge_k * prof.sigma:
+                    # re-issue on the fastest variant; overlap from
+                    # detection point
+                    fast = min(self.store.profiles.values(),
+                               key=lambda p: p.mu)
+                    if fast.name != name:
+                        detect = prof.mu + self.hedge_k * prof.sigma
+                        t2 = self.by_name[fast.name].run(tokens, n_decode)
+                        t_infer = min(t_infer, detect + t2)
+                        hedged = True
+                self.store.observe(name, t_infer)
+                e2e = 2.0 * t_input + t_queue + t_infer
+                res = RequestResult(
+                    variant=name, t_input_ms=t_input, t_infer_ms=t_infer,
+                    t_e2e_ms=e2e, t_sla_ms=t_sla, met_sla=e2e <= t_sla,
+                    quality=v.quality, hedged=hedged,
+                    w_queue_ms=dec.budget.w_queue_ms,
+                    tokens_served=n_decode + 1, t_queue_ms=t_queue)
+                self.results.append(res)
         return res
 
     # ------------------------------------------------------------------
@@ -158,8 +178,10 @@ class PoolExecutor:
         rs = self.results
         served = [r for r in rs if r.admitted]
         usage: Dict[str, int] = {}
+        tokens: Dict[str, int] = {}
         for r in served:
             usage[r.variant] = usage.get(r.variant, 0) + 1
+            tokens[r.variant] = tokens.get(r.variant, 0) + r.tokens_served
         e2e = [r.t_e2e_ms for r in served]
         return {
             "n": len(rs),
@@ -172,7 +194,12 @@ class PoolExecutor:
             "mean_latency_ms": float(np.mean(e2e)) if served else 0.0,
             "p95_latency_ms": float(np.percentile(e2e, 95)) if served else 0.0,
             "p99_latency_ms": float(np.percentile(e2e, 99)) if served else 0.0,
+            # over every request: a shed request waited too
+            "p95_queue_ms": float(np.percentile([r.t_queue_ms for r in rs],
+                                                95)),
             "hedged": sum(r.hedged for r in rs),
             "shed": len(rs) - len(served),
             "usage": {k: v / len(served) for k, v in sorted(usage.items())},
+            "tokens_served": sum(tokens.values()),
+            "tokens_served_by_member": dict(sorted(tokens.items())),
         }

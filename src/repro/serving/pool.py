@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import api, model as M
+from repro.obs import span
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "cache_len"))
@@ -71,19 +72,24 @@ class Variant:
         return self
 
     def run(self, tokens: np.ndarray, n_decode: int = 4) -> float:
-        """Execute prefill + n_decode steps; returns wall ms (blocking)."""
-        t0 = time.perf_counter()
-        tok = jnp.asarray(tokens)
-        cache, logits = self.prefill_fn(self.params, tok)
-        B, S = tokens.shape
-        pos = jnp.full((B,), S, jnp.int32)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        for _ in range(n_decode):
-            logits_d, cache = self.decode_fn(self.params, cache, nxt, pos)
-            nxt = jnp.argmax(logits_d, axis=-1).astype(jnp.int32)
-            pos = pos + 1
-        jax.block_until_ready(logits_d)
-        return (time.perf_counter() - t0) * 1e3
+        """Execute prefill + n_decode steps; returns wall ms (blocking).
+        With ``n_decode == 0`` the prefill alone runs and its logits are
+        waited for."""
+        with span("pool.run"):
+            t0 = time.perf_counter()
+            with span("pool.run.upload"):
+                tok = jnp.asarray(tokens)
+            cache, logits = self.prefill_fn(self.params, tok)
+            # positions are made on the device once the prefill is queued
+            B, S = tokens.shape
+            pos = jnp.full((B,), S, jnp.int32)
+            for _ in range(n_decode):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                logits, cache = self.decode_fn(self.params, cache, nxt, pos)
+                pos = pos + 1
+            with span("pool.run.sync"):
+                jax.block_until_ready(logits)
+            return (time.perf_counter() - t0) * 1e3
 
 
 def scaled_family(base: ModelConfig, *, widths=(0.25, 0.5, 1.0),
