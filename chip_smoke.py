@@ -17,7 +17,7 @@ One chip runs, in order and in this one process:
    with a seeded 150–600 ms SLA mix;
 5. selection — the fused Pallas selection at batch 4096 and 100k, its
    masks and probabilities against their references, and the charged
-   ``lax.scan`` routing path once at 4096.
+   routing path (the Pallas charged kernel) once at 4096.
 
 ``--four-chips`` runs only the fleet selection sharded over a 4-device
 ``cell`` mesh, against the single-device call.
@@ -239,7 +239,7 @@ def _budget_columns(B: int, seed: int):
 def phase_selection(batches=SELECT_BATCHES, charged_batch=CHARGED_BATCH, *,
                     seed: int = SEED):
     """The fused selection, its masks and stage-3 kernel against their
-    references, and the charged ``lax.scan`` routing path."""
+    references, and the charged routing path."""
     on_tpu = jax.default_backend() == "tpu"
     tab = _grid_table()
     pool = tab.device_pool()
@@ -301,13 +301,16 @@ def phase_selection(batches=SELECT_BATCHES, charged_batch=CHARGED_BATCH, *,
     after = policy_select._charged_jit.cache_info()
     if after.hits + after.misses == calls.hits + calls.misses:
         raise RuntimeError("the charged batch did not ride charged_select")
+    kernel = router.stats()["n_charged_kernel_batches"] == 1
+    if on_tpu and not kernel:
+        raise RuntimeError("the charged batch did not run the Pallas kernel")
     n = len(store.profiles)
     picks = res.model_idx[res.admitted]
     n_shed = int((~res.admitted).sum())
     if not (((picks >= 0) & (picks < n)).all()
             and np.isfinite(res.w_queue_ms).all()):
         raise RuntimeError("charged picks out of range or waits not finite")
-    say("select", f"charged lax.scan routing at {charged_batch}: "
+    say("select", f"charged routing at {charged_batch} (kernel={kernel}): "
                   f"{len(picks)} admitted, {n_shed} shed, "
                   f"{len(np.unique(picks))} distinct models")
 

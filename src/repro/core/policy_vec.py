@@ -82,7 +82,7 @@ def _resolve_backend(backend: Optional[str], n_batch: int) -> str:
 def resolve_backend(backend: Optional[str], n_batch: int) -> str:
     """Public backend resolution (``auto``/env/threshold → ``numpy`` or
     ``jax``) — the Router uses it to decide whether a charged batch can
-    ride the device-resident ``lax.scan`` pass in
+    ride the device-resident charged pass in
     ``kernels.policy_select.charged_select`` under the same policy as
     the uncharged fused pipeline."""
     return _resolve_backend(backend, n_batch)

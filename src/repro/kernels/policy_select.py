@@ -1,6 +1,6 @@
 """Device-resident ModiPick selection: fused stages 1–3 under one jit.
 
-Two layers live here:
+Three layers live here:
 
 - the **stage-3 Pallas TPU kernel** (``_probs_kernel`` /
   ``modipick_probs``): the fused eligibility-mask / Eq. 3–4 utility /
@@ -16,6 +16,11 @@ Two layers live here:
   elsewhere) and an inverse-CDF categorical draw, all under ONE jit.
   Input is ``(mu, sigma, acc, t_u, t_l)``; output is the sampled pool
   indices.  Nothing round-trips through the host between stages.
+- the **charged sequential-greedy pass** (``charged_select``): the same
+  stages per request against a per-replica wait ledger that every
+  admitted pick charges — one Pallas kernel on the TPU
+  (``_charged_kernel``), a ``lax.scan`` over ``_charged_step``
+  elsewhere.
 
 Compiled callables are cached per ``(pool_size, gamma, batch_block)``
 (``functools.lru_cache`` over the jit closure; XLA's own cache handles
@@ -34,11 +39,13 @@ oracle tests; ``kernels.ref`` holds the pure-jnp references.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.obs import span
 
@@ -461,8 +468,9 @@ def select_classed(stacked, cls, t_u, t_l, *, shifts=None,
 
 
 # ======================================================================
-# Charged sequential-greedy selection: lax.scan over the batch, with the
-# per-replica wait ledger as the carry.
+# Charged sequential-greedy selection: a sequential pass over the batch,
+# with the per-replica wait ledger as the carry — a lax.scan, or one
+# Pallas kernel on the TPU.
 # ======================================================================
 
 def _charged_step(rep_wait, xs, *, mu, sig, acc, rank, mu_charge,
@@ -516,11 +524,216 @@ def _charged_step(rep_wait, xs, *, mu, sig, acc, rank, mu_charge,
     return rep_wait, (pick, admitted, has_base[0], rep, w_chosen)
 
 
+# ----------------------------------------------------------------------
+# The same sequential-greedy loop as ONE Pallas TPU kernel.  The scan
+# above launches a dozen small XLA fusions per request, each paying a
+# launch and an HBM round trip for its carry; here every step of a
+# tick runs inside one kernel with the ledger, the pool rows and the
+# candidate topology resident in VMEM.
+#
+# Layout: the candidate matrix is transposed to (replicas × models), so
+# a model's least candidate wait is a sublane reduction that lands on
+# the pool's lane layout; the ledger rides beside it, broadcast over
+# the lanes.  Each request's four scalars come from SMEM; its five
+# results are gathered into one vreg tile per column and stored once
+# per block of rows.  The ledger lives in VMEM scratch across the
+# sequential ("arbitrary") grid over row blocks, so the kernel's VMEM
+# does not grow with the batch.
+# ----------------------------------------------------------------------
+
+# Rows per grid step: one (8, 128) result tile per column.
+CHARGED_BLOCK = 1024
+# VMEM for the kernel's (replicas × lanes) float32 planes: the candidate,
+# speed and initial-wait matrices (each double-buffered by the pipeline)
+# and the live ledger.  Beyond it the scan runs instead.
+CHARGED_VMEM_BYTES = 8 << 20
+
+
+def charged_kernel_engaged(npad: int, n_replicas: int) -> bool:
+    """Whether ``charged_select`` runs the Pallas kernel rather than the
+    ``lax.scan``: on the TPU backend, when the (replicas × models)
+    operands fit the kernel's VMEM budget."""
+    lanes = -(-npad // LANES) * LANES
+    rows = -(-n_replicas // 8) * 8
+    return (jax.default_backend() == "tpu"
+            and 7 * rows * lanes * 4 <= CHARGED_VMEM_BYTES)
+
+
+def _lane_cumsum(x, lane):
+    """Inclusive prefix sum along lanes: log-step roll-and-add."""
+    k = 1
+    while k < x.shape[1]:
+        x = x + jnp.where(lane >= k, pltpu.roll(x, k, 1), 0.0)
+        k *= 2
+    return x
+
+
+def _charged_kernel(pool_ref, cand_ref, spd_ref, wait_ref, rows_ref, out_ref,
+                    ledger_ref, *, slack: float, include_mu: bool,
+                    fastest: int):
+    """One block of rows of the charged pass; step for step what
+    :func:`_charged_step` does, in float32."""
+    rp, npad = cand_ref.shape
+    sub, lanes = out_ref.shape[1:]
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        ledger_ref[...] = wait_ref[...]
+
+    mu, sig = pool_ref[0:1, :], pool_ref[1:2, :]
+    accg, rank = pool_ref[2:3, :], pool_ref[3:4, :]
+    mu_charge = pool_ref[4:5, :]
+    cand = cand_ref[...] > 0.0
+    spd = spd_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, npad), 1).astype(f32)
+    rep_row = jax.lax.broadcasted_iota(jnp.int32, (rp, npad), 0).astype(f32)
+    slot = (jax.lax.broadcasted_iota(jnp.int32, (sub, lanes), 0) * lanes
+            + jax.lax.broadcasted_iota(jnp.int32, (sub, lanes), 1)
+            ).astype(f32)
+    inf = jnp.inf
+    no_rank = jnp.float32(PAD_RANK + 1.0)
+
+    def lane_min(x):
+        return jnp.min(x, axis=1, keepdims=True)
+
+    def at(onehot, x):
+        return jnp.max(jnp.where(onehot, x, -inf), axis=1, keepdims=True)
+
+    def step(j, tiles):
+        tu, tl, r01, lim = (rows_ref[0, k, j] for k in range(4))
+        ledger = ledger_ref[...]
+        # Per model: least candidate wait (+inf without candidates), its
+        # first least-waiting replica (argmin's first index, 0 where
+        # every entry is +inf) and that replica's speed.
+        masked = jnp.where(cand, ledger, inf)
+        wq_raw = jnp.min(masked, axis=0, keepdims=True)
+        first = jnp.min(jnp.where(masked == wq_raw, rep_row, float(rp)),
+                        axis=0, keepdims=True)
+        speed = jnp.max(jnp.where(rep_row == first, spd, -inf), axis=0,
+                        keepdims=True)
+        wq = jnp.where(jnp.abs(wq_raw) < inf, wq_raw, 0.0)
+
+        cost = wq_raw + slack
+        if include_mu:
+            cost = cost + mu_charge
+        admitted = jnp.max(jnp.where(cost < lim, 1.0, 0.0), axis=1,
+                           keepdims=True) > 0.0
+
+        # Stages 1-2 on the shifted profiles.  Real lanes carry distinct
+        # ranks, so wherever a base exists the least rank marks one lane.
+        mu_i = mu + wq
+        mus = mu_i + sig
+        elig1 = (mus < tu) & ((mu_i - sig) < tl)
+        keyed = jnp.where(elig1, rank, no_rank)
+        rmin = lane_min(keyed)
+        has_base = jnp.max(jnp.where(elig1, 1.0, 0.0), axis=1,
+                           keepdims=True) > 0.0
+        is_base = keyed == rmin
+        half = at(is_base, jnp.abs(tl - mu_i) + sig)
+        lo, hi = tl - half, tl + half
+        natural = (lo <= mu_i) & (mu_i <= hi) & (mus < tu)
+        eligible = (natural | is_base) & has_base
+
+        # Eq. 3-4 utilities and the inverse-CDF draw.  Both candidate
+        # weight rows are scanned at once; the degenerate-row test picks.
+        u = jnp.where(eligible, accg * (tu - mus)
+                      / jnp.maximum(jnp.abs(tl - mu_i), EPS), 0.0)
+        total_u = jnp.sum(u, axis=1, keepdims=True)
+        good = (jnp.abs(total_u) < inf) & (total_u > 0.0)
+        cdf = jnp.where(good, _lane_cumsum(u, lane),
+                        _lane_cumsum(jnp.where(eligible, 1.0, 0.0), lane))
+        total = at(lane == float(npad - 1), cdf)
+        thresh = r01 * total
+        choice = lane_min(jnp.where(cdf > thresh, lane, float(npad)))
+        hit_cdf = total > thresh
+        drawn = (hit_cdf & (lane == choice)) | (~hit_cdf & is_base)
+        picked = (has_base & drawn) | (~has_base & (lane == float(fastest)))
+
+        # Charge the pick to its least-waiting replica.
+        rep = at(picked, first)
+        delta = jnp.where(admitted, at(picked, mu_charge / speed), 0.0)
+        ledger_ref[...] = jnp.where(rep_row == rep, ledger + delta, ledger)
+
+        w_chosen = jnp.where(admitted, at(picked, wq), lane_min(wq_raw))
+        row = (at(picked, lane), jnp.where(admitted, 1.0, 0.0),
+               jnp.where(has_base, 1.0, 0.0), rep, w_chosen)
+        hit = slot == j.astype(f32)
+        return tuple(jnp.where(hit, v, t) for v, t in zip(row, tiles))
+
+    zero = jnp.zeros((sub, lanes), f32)
+    tiles = jax.lax.fori_loop(0, sub * lanes, step, (zero,) * 5)
+    for k, t in enumerate(tiles):
+        out_ref[k] = t
+
+
+def _charged_pallas(mu, sig, acc, rank, mu_charge, cand_mask, speed,
+                    rep_wait, t_u, t_l, r01, lim, *, gamma: float,
+                    slack: float, include_mu: bool, fastest: int,
+                    interpret: bool = False):
+    """The charged pass as one ``pallas_call``; same operands and the
+    same five result columns as the scan."""
+    f32 = jnp.float32
+    n = mu.shape[0]
+    R = rep_wait.shape[0]
+    npad = -(-n // LANES) * LANES
+    rp = -(-R // 8) * 8
+    B = t_u.shape[0]
+    b8 = -(-B // 8) * 8
+    block = math.gcd(b8, CHARGED_BLOCK)
+    nblk = b8 // block
+
+    # Pool rows (one sublane tile): mu, sigma, acc**gamma, rank, charge-mu.
+    pool = jnp.stack([
+        jnp.pad(x.astype(f32), (0, npad - n), constant_values=v)
+        for x, v in ((mu, PAD_MU), (sig, 0.0),
+                     (jnp.power(jnp.maximum(acc, EPS), gamma), 1.0),
+                     (rank, PAD_RANK), (mu_charge, 0.0))])
+    pool = jnp.pad(pool, ((0, 3), (0, 0)))
+    cand = jnp.pad(cand_mask.T.astype(f32), ((0, rp - R), (0, npad - n)))
+    col = lambda x, v: jnp.broadcast_to(
+        jnp.pad(x.astype(f32), (0, rp - R), constant_values=v)[:, None],
+        (rp, npad))
+    rows = jnp.stack([jnp.pad(x.astype(f32), (0, b8 - B), constant_values=v)
+                      for x, v in ((t_u, 0.0), (t_l, 0.0), (r01, 0.0),
+                                   (lim, -jnp.inf))])
+    rows = rows.reshape(4, nblk, block).transpose(1, 0, 2)
+
+    whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
+    out = pl.pallas_call(
+        functools.partial(_charged_kernel, slack=slack,
+                          include_mu=include_mu, fastest=fastest),
+        grid=(nblk,),
+        in_specs=[whole(pool.shape), whole((rp, npad)),
+                  whole((rp, npad)), whole((rp, npad)),
+                  pl.BlockSpec((1, 4, block), lambda i: (i, 0, 0),
+                               memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((5, 8, block // 8), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((5, 8 * nblk, block // 8), f32),
+        scratch_shapes=[pltpu.VMEM((rp, npad), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(pool, cand, col(speed, 1.0), col(rep_wait, 0.0), rows)
+    out = out.reshape(5, nblk, 8, block // 8).reshape(5, b8)[:, :B]
+    return (out[0].astype(jnp.int32), out[1] > 0.0, out[2] > 0.0,
+            out[3].astype(jnp.int32), out[4])
+
+
 @functools.lru_cache(maxsize=32)
 def _charged_jit(npad: int, gamma: float, slack: float, include_mu: bool,
-                 fastest: int):
+                 fastest: int, use_kernel: bool = False,
+                 interpret: bool = False):
+    """The charged pass's jit cache: the ``lax.scan`` over
+    :func:`_charged_step`, or with ``use_kernel`` the Pallas kernel
+    (``interpret`` runs it through the Pallas interpreter)."""
     def run(mu, sig, acc, rank, mu_charge, cand_mask, speed, rep_wait,
             t_u, t_l, r01, lim):
+        if use_kernel:
+            return _charged_pallas(
+                mu, sig, acc, rank, mu_charge, cand_mask, speed, rep_wait,
+                t_u, t_l, r01, lim, gamma=gamma, slack=slack,
+                include_mu=include_mu, fastest=fastest, interpret=interpret)
         step = functools.partial(
             _charged_step, mu=mu, sig=sig, acc=acc, rank=rank,
             mu_charge=mu_charge, cand_mask=cand_mask, speed=speed,
@@ -535,11 +748,14 @@ def charged_select(pool: DevicePool, t_u, t_l, state, *,
                    gamma: float = 1.0, adm_limit=None,
                    adm_slack: float = 0.0, adm_include_mu: bool = False,
                    seed: int = 0, block_b: int = 256):
-    """Device-resident charged batch selection: a ``lax.scan`` over the
-    batch whose carry is the per-replica wait ledger, so request ``i``
-    is admitted and selected against waits that include the charges of
-    requests ``0..i-1`` — the sequential-greedy staleness fix, riding
-    the same fused stage-1–3 math as :func:`select_fused`.
+    """Device-resident charged batch selection: a sequential pass over
+    the batch whose carry is the per-replica wait ledger, so request
+    ``i`` is admitted and selected against waits that include the
+    charges of requests ``0..i-1`` — the sequential-greedy staleness
+    fix, riding the same fused stage-1–3 math as :func:`select_fused`.
+    The pass is one Pallas kernel where :func:`charged_kernel_engaged`
+    says so (the TPU, replicas within its VMEM budget), else a
+    ``lax.scan`` over :func:`_charged_step`.
 
     ``state`` is a :class:`repro.router.charging.ChargedWaits` (replica
     waits, model → candidate topology, speeds, live charge-μ).
@@ -583,8 +799,10 @@ def charged_select(pool: DevicePool, t_u, t_l, state, *,
         r01 = jax.random.uniform(jax.random.PRNGKey(seed), (bpad,),
                                  dtype=f32)
 
+    kernel = charged_kernel_engaged(npad, R)
     fn = _charged_jit(npad, float(gamma), float(adm_slack),
-                      bool(adm_include_mu), pool.fastest)
+                      bool(adm_include_mu), pool.fastest, kernel,
+                      kernel and jax.default_backend() != "tpu")
     with span("router.select.readback"):
         picks, admitted, has_base, rep, w_chosen = fn(*args, r01, lim_dev)
         return (np.asarray(picks)[:B], np.asarray(admitted)[:B],

@@ -103,9 +103,9 @@ class SlaAwareAdmission(AdmissionController):
     budget is already non-positive — the network alone ate the SLA — is
     always shed: every ``W_queue ≥ 0`` exceeds it.
 
-    The charged ``lax.scan`` kernel
+    The device charged pass
     (:func:`repro.kernels.policy_select.charged_select`) inlines this
-    exact viability test against the in-scan charged waits, which is why
+    exact viability test against the in-pass charged waits, which is why
     the Router's scan fast path dispatches only for this controller (or
     :class:`AdmitAll`) — their verdicts are reproducible inside the
     kernel.

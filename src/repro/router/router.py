@@ -47,8 +47,9 @@ Per batch, the router:
    ``policy.select_traced``/``select_lean`` (draw-for-draw identical to
    the historical per-request call sites, which is what keeps seeded
    single-SLA goldens bit-identical); a charged batch rides the same
-   scalar core sequentially (or the device-resident ``lax.scan`` pass in
-   ``kernels.policy_select`` on the jax backend); an uncharged batch
+   scalar core sequentially (or the device-resident charged pass in
+   ``kernels.policy_select`` on the jax backend: one Pallas kernel on
+   the TPU, a ``lax.scan`` elsewhere); an uncharged batch
    rides the vectorized ``policy_vec.select_batch_traced``.
 
 Queue-aware mode presents the policy with the shifted-μ store view
@@ -110,8 +111,10 @@ class Router:
         self.n_shed = 0
         self.n_fallback = 0
         self.n_batches = 0
-        # Batches that rode the device charged scan (the jax backend).
+        # Batches that rode the device charged scan (the jax backend),
+        # and those of them that ran as the Pallas kernel.
         self.n_scan_batches = 0
+        self.n_charged_kernel_batches = 0
         # Recovery path (router.retry): re-route requests and outcomes.
         self.n_retries = 0
         self.n_retry_routed = 0
@@ -602,7 +605,7 @@ class Router:
 
     # -- device path ---------------------------------------------------
     def _use_charged_scan(self, B: int) -> bool:
-        """The ``lax.scan`` charged pass engages under the same backend
+        """The device charged pass engages under the same backend
         policy as the uncharged fused pipeline (ModiPick, large batch or
         an explicit jax backend), for controllers whose verdict is the
         pure viability test the kernel can evaluate in-scan."""
@@ -625,14 +628,17 @@ class Router:
             slack = adm.slack_ms
             include_mu = adm.include_service_time
         tab = self.store.table()
+        pool = tab.device_pool()
         out = policy_select.charged_select(
-            tab.device_pool(), budgets,
+            pool, budgets,
             budgets - self.policy.t_threshold,
             state, gamma=self.policy.gamma,
             adm_limit=adm_limit, adm_slack=slack,
             adm_include_mu=include_mu,
             seed=int(rng.integers(np.iinfo(np.int64).max)))
         self.n_scan_batches += 1
+        self.n_charged_kernel_batches += policy_select.charged_kernel_engaged(
+            pool.npad, len(state.rep_wait))
         picks, admitted, has_base, replica, w_chosen = out
         names = tab.names
         with span("router.apply"):
@@ -726,6 +732,7 @@ class Router:
         self.n_fallback = 0
         self.n_batches = 0
         self.n_scan_batches = 0
+        self.n_charged_kernel_batches = 0
         self.n_retries = 0
         self.n_retry_routed = 0
         self.n_retry_exhausted = 0
@@ -760,6 +767,7 @@ class Router:
             "n_fallback": self.n_fallback,
             "n_batches": self.n_batches,
             "n_scan_batches": self.n_scan_batches,
+            "n_charged_kernel_batches": self.n_charged_kernel_batches,
             "n_retries": self.n_retries,
             "n_retry_routed": self.n_retry_routed,
             "n_retry_exhausted": self.n_retry_exhausted,

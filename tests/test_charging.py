@@ -13,7 +13,9 @@ Covers, in order:
 - the array-native ``route_batch_arrays`` column contract;
 - the ``lax.scan`` charged kernel (forced jax backend) against the
   numpy sequential loop on a deterministic single-model pool, plus
-  multi-model sanity.
+  multi-model sanity;
+- the Pallas charged kernel (interpret mode) against the ``lax.scan``
+  on the zoo's shape, and the router's kernel counter.
 """
 import numpy as np
 import pytest
@@ -345,3 +347,90 @@ def test_charged_scan_multimodel_spreads_and_places():
     got = float(np.sum(state2.rep_wait))
     want = sum(float(tab.mu[m]) for m in res2.model_idx)
     assert got == pytest.approx(want, rel=1e-12)
+
+
+# ----------------------------------------------------------------------
+# the Pallas charged kernel (interpret mode here) against the lax.scan
+# ----------------------------------------------------------------------
+
+def _zoo_charged(B, rpm, tied, seed):
+    """The paper's 11-model zoo behind ``rpm`` replicas a model, with
+    Table 2 budgets: uplinks wide enough that some rows find no base
+    model and some are shed.  ``tied`` draws replica waits from a few
+    values so that equal waits (the first-index tie-break) are common."""
+    from repro.core.zoo import make_store
+    tab = make_store(TABLE2).table()
+    n = len(tab.names)
+    rng = np.random.default_rng(seed)
+    R = n * rpm
+    waits = (rng.integers(0, 4, R) * 25.0 if tied
+             else rng.uniform(0, 100, R))
+    state = ChargedWaits(waits, [range(m * rpm, (m + 1) * rpm)
+                                 for m in range(n)],
+                         rng.choice([1.0, 2.0], R), tab.mu, tab.names)
+    budgets = (rng.uniform(150, 600, B)
+               - 2 * np.maximum(rng.normal(57.87, 30.78, B), 0.1))
+    return tab, state, budgets
+
+
+@pytest.mark.parametrize("B, admission, include_mu, tied", [
+    (4096, "sla", False, False),
+    (1200, "sla", True, True),       # padded to 1280: blocks of 256 rows
+    (300, "all", False, True),
+])
+def test_charged_kernel_matches_scan(monkeypatch, B, admission, include_mu,
+                                     tied):
+    """The Pallas kernel (interpreted) and the ``lax.scan`` give the same
+    picks, verdicts, fallbacks and replicas, and the same waits, on the
+    zoo's 11 models × 176 replicas: an admit/shed mix (or admit-all),
+    rows with no base model, tied replica waits, padded batch rows."""
+    from repro.kernels import policy_select
+    tab, state, budgets = _zoo_charged(B, 16, tied, seed=B)
+    kw = dict(gamma=1.0, seed=7, adm_include_mu=include_mu,
+              adm_limit=budgets if admission == "sla" else None)
+    args = (tab.device_pool(), budgets, budgets - 20.0, state)
+    scan = policy_select.charged_select(*args, **kw)
+    monkeypatch.setattr(policy_select, "charged_kernel_engaged",
+                        lambda npad, n_replicas: True)
+    kernel = policy_select.charged_select(*args, **kw)
+    picks, admitted, has_base, rep, w = scan
+    for name, want, got in zip(("picks", "admitted", "has_base", "replica"),
+                               scan[:4], kernel[:4]):
+        np.testing.assert_array_equal(got, want, err_msg=name)
+    np.testing.assert_allclose(kernel[4], w, rtol=1e-6)
+    # the cases reach what they name
+    assert (~has_base).any() and has_base.any()
+    assert admitted.all() if admission == "all" else (~admitted).any()
+    if tied:
+        ties = [np.sum(state.rep_wait[c] == state.rep_wait[c].min()) > 1
+                for c in state.cand]
+        assert any(ties)
+
+
+def test_charged_kernel_counter(monkeypatch):
+    """Off the TPU the charged tick takes the ``lax.scan``: the kernel
+    counter stays 0 while ``n_scan_batches`` counts; a tick through the
+    kernel counts in both; both reset."""
+    from repro.core.zoo import make_store
+    from repro.kernels import policy_select
+    tab, state, budgets = _zoo_charged(64, 2, False, seed=3)
+    router = Router(make_store(TABLE2), ModiPick(t_threshold=20.0),
+                    admission=SlaAwareAdmission(), queue_aware=True,
+                    trace_detail=False, backend="jax")
+
+    def tick():
+        router.route_batch_arrays(budgets + 100.0, np.full(64, 50.0),
+                                  np.random.default_rng(0), charged=state)
+    tick()
+    tick()
+    s = router.stats()
+    assert s["n_scan_batches"] == 2 and s["n_charged_kernel_batches"] == 0
+    assert router.window_stats()["n_charged_kernel_batches"] == 0
+    monkeypatch.setattr(policy_select, "charged_kernel_engaged",
+                        lambda npad, n_replicas: True)
+    tick()
+    win = router.window_stats()
+    assert win["n_scan_batches"] == 1 and win["n_charged_kernel_batches"] == 1
+    router.reset()
+    assert router.stats()["n_scan_batches"] == 0
+    assert router.stats()["n_charged_kernel_batches"] == 0

@@ -80,6 +80,23 @@ def test_charged_select_compiles(one_chip):
     assert compiled.as_text()
 
 
+@pytest.mark.parametrize("batch", [4096, 1200])
+def test_charged_select_kernel_compiles(one_chip, batch):
+    """The zoo's charged pass (128 lanes × 176 replicas) as the Pallas
+    kernel: one ``tpu_custom_call``, at a full tick and at one whose rows
+    are not a multiple of the kernel's row block."""
+    lanes, replicas = policy_select.LANES, 176
+    fn = policy_select._charged_jit(lanes, 1.0, 0.0, True, 1, True)
+    f32 = jnp.float32
+    vec = _sds((lanes,), f32, one_chip)
+    rep = _sds((replicas,), f32, one_chip)
+    req = _sds((batch,), f32, one_chip)
+    compiled = fn.lower(vec, vec, vec, vec, vec,
+                        _sds((lanes, replicas), jnp.bool_, one_chip),
+                        rep, rep, req, req, req, req).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 def test_pool_members_compile_and_fit_one_chip(one_chip):
     """qwen2-1.5b and phi4-mini-3.8b at published widths in bf16: the
     served prefill and decode step compile, and the two members'
