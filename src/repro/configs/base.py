@@ -14,7 +14,7 @@ from dataclasses import dataclass, field, replace
 from typing import Optional, Tuple
 
 # Block kinds understood by the model substrate.
-BLOCK_KINDS = ("attn", "local", "rglru", "ssd")
+BLOCK_KINDS = ("attn", "local", "mla", "rglru", "ssd")
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -30,6 +30,32 @@ class MoEConfig:
     # Token-group size for the GShard-style one-hot dispatch einsum.  Kept
     # modest so the (g, E, C) dispatch tensor stays VMEM/HBM friendly.
     group_size: int = 512
+    n_shared: int = 0              # shared experts, run for every token
+    scoring: str = "softmax"       # softmax | sigmoid (with a correction bias)
+    routed_scale: float = 1.0      # multiplies the top-k gates, renormalised to sum 1
+    # Experts [0, n_held) live here (one chip's expert-parallel share);
+    # None holds all n_experts.  Routing always runs over all n_experts.
+    n_held: Optional[int] = None
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values are
+    rebuilt per head from one shared latent of ``kv_lora_rank`` values,
+    and one ``qk_rope_head_dim`` rotary key is shared by all heads.
+    Queries come from one full projection (no query latent)."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -98,6 +124,10 @@ class ModelConfig:
     norm_eps: float = 1e-6
     logit_softcap: float = 0.0
     moe: Optional[MoEConfig] = None
+    # Leading layers whose FFN is a dense MLP of width d_ff even where
+    # the rest route to experts; they run unrolled ahead of the scan.
+    first_k_dense: int = 0
+    mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
     encdec: Optional[EncDecConfig] = None
@@ -120,18 +150,26 @@ class ModelConfig:
 
     @property
     def block_kinds(self) -> Tuple[str, ...]:
-        """Per-layer kinds: pattern repeated with the remainder as a tail."""
-        reps = self.n_layers // len(self.pattern)
-        tail = self.n_layers - reps * len(self.pattern)
-        return self.pattern * reps + self.pattern[:tail]
+        """Per-layer kinds: the leading dense layers, then the pattern
+        repeated with the remainder as a tail."""
+        n = self.n_layers - self.first_k_dense
+        reps = n // len(self.pattern)
+        tail = n - reps * len(self.pattern)
+        return (self.lead_kinds + self.pattern * reps
+                + self.pattern[:tail])
+
+    @property
+    def lead_kinds(self) -> Tuple[str, ...]:
+        return (self.pattern[0],) * self.first_k_dense
 
     @property
     def n_superblocks(self) -> int:
-        return self.n_layers // len(self.pattern)
+        return (self.n_layers - self.first_k_dense) // len(self.pattern)
 
     @property
     def tail_kinds(self) -> Tuple[str, ...]:
-        return self.pattern[: self.n_layers - self.n_superblocks * len(self.pattern)]
+        n = self.n_layers - self.first_k_dense
+        return self.pattern[: n - self.n_superblocks * len(self.pattern)]
 
     @property
     def is_attention_free(self) -> bool:
@@ -139,7 +177,7 @@ class ModelConfig:
 
     @property
     def has_global_attention(self) -> bool:
-        return any(k == "attn" for k in self.block_kinds)
+        return any(k in ("attn", "mla") for k in self.block_kinds)
 
     @property
     def sub_quadratic(self) -> bool:
@@ -148,18 +186,38 @@ class ModelConfig:
         SSM / hybrid / mostly-local archs qualify; sparse global layers
         (gemma3 1-in-6) are handled with context-parallel KV."""
         kinds = self.block_kinds
-        n_global = sum(1 for k in kinds if k == "attn")
+        n_global = sum(1 for k in kinds if k in ("attn", "mla"))
         return n_global == 0 or (n_global / len(kinds)) <= 0.25
 
-    def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks), for rooflines."""
+    def _attn_params(self) -> int:
         d, hd = self.d_model, self.resolved_head_dim
+        if self.mla is not None:
+            a, H = self.mla, self.n_heads
+            return (d * H * a.qk_head_dim                       # wq
+                    + d * (a.kv_lora_rank + a.qk_rope_head_dim)  # wkv_a
+                    + a.kv_lora_rank                             # kv_norm
+                    + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim)
+                    + H * a.v_head_dim * d)                      # wo
+        return d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+
+    def _moe_params(self, held: bool = True) -> int:
+        """One expert layer: router (and its bias), routed experts (the
+        held share where ``held``), shared experts."""
+        d, e = self.d_model, self.moe
+        n = d * e.n_experts + (e.n_experts if e.scoring == "sigmoid" else 0)
+        n += (e.held if held else e.n_experts) * 3 * d * e.d_ff_expert
+        return n + e.n_shared * 3 * d * e.d_ff_expert
+
+    def param_count(self) -> int:
+        """Analytic parameter count (embedding + blocks), for rooflines.
+        An expert share counts the experts it holds."""
+        d = self.d_model
         n = self.padded_vocab * d  # embedding
         if not self.tie_embeddings:
             n += self.padded_vocab * d
-        for kind in self.block_kinds:
-            if kind in ("attn", "local"):
-                n += d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        for li, kind in enumerate(self.block_kinds):
+            if kind in ("attn", "local", "mla"):
+                n += self._attn_params()
             elif kind == "ssd":
                 s = self.ssm
                 di = s.d_inner(d)
@@ -172,15 +230,14 @@ class ModelConfig:
                 w = self.rglru.width(d)
                 n += 2 * d * w + w * self.rglru.conv_width + 2 * w * w + 4 * w + w * d
             if kind != "ssd":  # MLP for every non-ssd block
-                if self.moe is not None:
-                    e = self.moe
-                    n += d * e.n_experts  # router
-                    n += e.n_experts * (3 * d * e.d_ff_expert)
+                if self.moe is not None and li >= self.first_k_dense:
+                    n += self._moe_params()
                 else:
                     mults = 3 if self.mlp == "swiglu" else 2
                     n += mults * d * self.d_ff
             n += 2 * d  # two norms
         if self.encdec is not None:
+            hd = self.resolved_head_dim
             enc_block = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
             enc_block += (3 if self.mlp == "swiglu" else 2) * d * self.d_ff + 2 * d
             n += self.encdec.n_encoder_layers * enc_block
@@ -188,22 +245,34 @@ class ModelConfig:
             n += self.n_layers * (d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d + d)
         return n
 
+    @property
+    def n_moe_layers(self) -> int:
+        if self.moe is None:
+            return 0
+        return sum(1 for li, k in enumerate(self.block_kinds)
+                   if k != "ssd" and li >= self.first_k_dense)
+
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only routed experts)."""
         if self.moe is None:
             return self.param_count()
         e = self.moe
-        dense_experts = e.n_experts * 3 * self.d_model * e.d_ff_expert
-        active_experts = e.top_k * 3 * self.d_model * e.d_ff_expert
-        per_layer_delta = dense_experts - active_experts
-        n_moe_layers = sum(1 for k in self.block_kinds if k != "ssd")
-        return self.param_count() - n_moe_layers * per_layer_delta
+        per_layer_delta = (e.held - e.top_k) * 3 * self.d_model * e.d_ff_expert
+        return self.param_count() - self.n_moe_layers * per_layer_delta
+
+    def expert_share(self, n_held: int) -> "ModelConfig":
+        """This chip's share of an expert-parallel deployment: experts
+        ``[0, n_held)`` of every expert layer, routed over all
+        ``n_experts`` as published; everything else whole."""
+        if self.moe is None or not 0 < n_held <= self.moe.n_experts:
+            raise ValueError(f"{self.name}: cannot hold {n_held} experts")
+        return replace(self, moe=replace(self.moe, n_held=n_held))
 
     # ------------------------------------------------------------------
     def reduced(self) -> "ModelConfig":
         """Small same-family variant for CPU smoke tests."""
         pat = len(self.pattern)
-        n_layers = max(2 * pat, pat + 1) if pat > 1 else 2
+        n_layers = (max(2 * pat, pat + 1) if pat > 1 else 2) + self.first_k_dense
         kw = dict(
             name=self.name + "-reduced",
             n_layers=n_layers,
@@ -217,7 +286,13 @@ class ModelConfig:
         )
         cfg = replace(self, **kw)
         if self.moe is not None:
-            cfg = replace(cfg, moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=64, group_size=32))
+            e = self.moe
+            cfg = replace(cfg, moe=replace(
+                e, n_experts=4, top_k=2, d_ff_expert=64, group_size=32,
+                n_held=None if e.n_held is None else min(e.n_held, 2)))
+        if self.mla is not None:
+            cfg = replace(cfg, mla=MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                                             qk_rope_head_dim=8, v_head_dim=16))
         if self.ssm is not None:
             cfg = replace(cfg, ssm=SSMConfig(d_state=16, head_dim=16, chunk_size=32))
         if self.rglru is not None:

@@ -9,7 +9,7 @@ from repro.configs import (
     gemma3_4b,
     internvl2_2b,
     mamba2_1_3b,
-    moonshot_v1_16b_a3b,
+    moonlight_16b_a3b,
     phi4_mini_3_8b,
     qwen2_1_5b,
     recurrentgemma_2b,
@@ -26,7 +26,7 @@ _FACTORIES: Dict[str, Callable[[], ModelConfig]] = {
     "gemma3-4b": gemma3_4b.config,
     "whisper-tiny": whisper_tiny.config,
     "dbrx-132b": dbrx_132b.config,
-    "moonshot-v1-16b-a3b": moonshot_v1_16b_a3b.config,
+    "moonlight-16b-a3b": moonlight_16b_a3b.config,
     "internvl2-2b": internvl2_2b.config,
 }
 

@@ -41,7 +41,7 @@ TRAIN_GRAD_ACCUM = {
     "gemma3-4b": 4,
     "whisper-tiny": 2,
     "dbrx-132b": 16,
-    "moonshot-v1-16b-a3b": 4,
+    "moonlight-16b-a3b": 4,
     "internvl2-2b": 2,
 }
 
@@ -83,6 +83,7 @@ def make_rules(cfg: ModelConfig, shape: ShapeConfig, mesh,
         "cache_seq": None,
         "heads_merged": tp,
         "kv_merged": tp,
+        "kv_lora": None,    # latent attention's shared latent: replicated
         "embed_fsdp": None,
     }
 

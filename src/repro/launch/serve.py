@@ -7,6 +7,11 @@ uniformly from ``[--sla-min-ms, --sla-max-ms]``.
 
   PYTHONPATH=src python -m repro.launch.serve \
       --archs qwen2-1.5b,phi4-mini-3.8b --policy modipick --requests 32
+
+An expert member is built as one chip's share of an expert-parallel
+deployment (``--experts-held`` of its experts, ``ModelConfig.expert_share``):
+``--archs qwen2-1.5b,moonlight-16b-a3b`` holds 8 of Moonlight's 64 experts
+per layer, the share of one chip in a group of 8.
 """
 from __future__ import annotations
 
@@ -71,11 +76,15 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--decode", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--experts-held", type=int, default=8,
+                    help="experts per layer an expert member holds here")
     args = ap.parse_args(argv)
 
     enable_compile_cache()
-    variants = build_pool([get_config(a) for a in args.archs.split(",")],
-                          seed=args.seed, cache_len=args.seq + 16)
+    cfgs = [get_config(a) for a in args.archs.split(",")]
+    cfgs = [c.expert_share(min(args.experts_held, c.moe.n_experts)) if c.moe else c
+            for c in cfgs]
+    variants = build_pool(cfgs, seed=args.seed, cache_len=args.seq + 16)
     tokens = np.random.default_rng(args.seed).integers(
         0, 500, (args.batch, args.seq), dtype=np.int32)
     ex = serve(variants, make_policy(args.policy, **args.policy_kwargs),

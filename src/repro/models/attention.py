@@ -84,8 +84,8 @@ def _grouped(q, n_kv):
 def attention_full(q, k, v, q_positions, kv_positions, *, causal=True,
                    q_chunk=2048, kv_chunk=2048, dynamic_skip=False):
     """Causal full attention: q-chunked outer loop × online-softmax kv
-    scan (flash attention in portable XLA).  q: (B,Sq,H,hd); k/v:
-    (B,Skv,KV,hd).  Never materializes (Sq, Skv) scores: per (q-block,
+    scan (flash attention in portable XLA).  q: (B,Sq,H,hd); k:
+    (B,Skv,KV,hd); v: (B,Skv,KV,hv), whose width may differ from hd.  Never materializes (Sq, Skv) scores: per (q-block,
     kv-block) tiles are fp32 but transient, the carried state is
     (m, l, acc).
 
@@ -117,11 +117,12 @@ def attention_full(q, k, v, q_positions, kv_positions, *, causal=True,
     n_q = Sq // cq
     n_k = Skv // ck
     G = H // KV
+    hv = v.shape[-1]
     scale = hd ** -0.5
 
-    # kv blocks as scan xs: (n_k, B, ck, KV, hd)
+    # kv blocks as scan xs: (n_k, B, ck, KV, hd|hv)
     kb = jnp.moveaxis(k.reshape(B, n_k, ck, KV, hd), 1, 0)
-    vb = jnp.moveaxis(v.reshape(B, n_k, ck, KV, hd), 1, 0)
+    vb = jnp.moveaxis(v.reshape(B, n_k, ck, KV, hv), 1, 0)
     pb = jnp.moveaxis(kv_positions.reshape(B, n_k, ck), 1, 0)
 
     def one_q_chunk(i):
@@ -150,7 +151,7 @@ def attention_full(q, k, v, q_positions, kv_positions, *, causal=True,
 
         m0 = jnp.full((B, KV, G, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, cq), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, cq, hd), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, cq, hv), jnp.float32)
         # Causal block skipping: q-chunk i only needs kv blocks covering
         # positions ≤ (i+1)·cq − 1 (standard contiguous positions; the
         # elementwise mask still guards exactness).  Halves attention
@@ -178,15 +179,15 @@ def attention_full(q, k, v, q_positions, kv_positions, *, causal=True,
             m, l, acc = jax.lax.fori_loop(0, hi, fori_body, (m0, l0, a0))
         else:
             (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), (kb, vb, pb))
-        out = acc / jnp.maximum(l, 1e-30)[..., None]  # (B,KV,G,cq,hd)
-        return jnp.moveaxis(out, 3, 1).astype(q.dtype)  # (B,cq,KV,G,hd)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]  # (B,KV,G,cq,hv)
+        return jnp.moveaxis(out, 3, 1).astype(q.dtype)  # (B,cq,KV,G,hv)
 
     if n_q == 1:
         out = one_q_chunk(0)
     else:
         out = _chunk_loop(one_q_chunk, n_q)
-        out = jnp.moveaxis(out, 0, 1).reshape(B, Sq, KV, G, hd)
-    return out.reshape(B, Sq, H, hd)
+        out = jnp.moveaxis(out, 0, 1).reshape(B, Sq, KV, G, hv)
+    return out.reshape(B, Sq, H, hv)
 
 
 def attention_windowed(q, k, v, q_positions, kv_positions, *, window, q_chunk=512):

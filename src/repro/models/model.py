@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import shard
 from repro.models import attention as attn
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import runtime_flags
@@ -31,11 +32,15 @@ Params = Dict[str, Any]
 # ======================================================================
 # Templates
 # ======================================================================
-def block_template(cfg: ModelConfig, kind: str) -> dict:
+def block_template(cfg: ModelConfig, kind: str, dense: bool = False) -> dict:
+    """One layer's parameters; ``dense`` gives a leading layer a dense
+    MLP of width ``d_ff`` where the others route to experts."""
     d = cfg.d_model
     t = {"norm1": norm_template(d)}
     if kind in ("attn", "local", "enc"):
         t["attn"] = attn.attn_template(cfg)
+    elif kind == "mla":
+        t["attn"] = mla_mod.mla_template(cfg)
     elif kind == "xdec":
         t["attn"] = attn.attn_template(cfg)
         t["norm_x"] = norm_template(d)
@@ -48,13 +53,17 @@ def block_template(cfg: ModelConfig, kind: str) -> dict:
     else:
         raise ValueError(kind)
     t["norm2"] = norm_template(d)
-    t["mlp"] = moe_mod.moe_template(cfg) if cfg.moe else mlp_template(d, cfg.d_ff, cfg.mlp)
+    t["mlp"] = (moe_mod.moe_template(cfg) if cfg.moe and not dense
+                else mlp_template(d, cfg.d_ff, cfg.mlp))
     return t
 
 
 def param_template(cfg: ModelConfig) -> dict:
     d, v = cfg.d_model, cfg.padded_vocab
     t: dict = {"embed": {"table": ParamSpec((v, d), ("vocab", "embed_fsdp"))}}
+    if cfg.first_k_dense:
+        t["lead"] = {f"l{i}": block_template(cfg, k, dense=True)
+                     for i, k in enumerate(cfg.lead_kinds)}
     if cfg.n_superblocks > 0:
         t["blocks"] = {
             f"p{i}": stack_specs(block_template(cfg, decoder_kind(cfg, k)), cfg.n_superblocks)
@@ -81,9 +90,19 @@ def decoder_kind(cfg: ModelConfig, kind: str) -> str:
     return kind
 
 
-def block_cache_template(cfg: ModelConfig, kind: str, batch: int, cache_len: int) -> dict:
+def block_cache_template(cfg: ModelConfig, kind: str, batch: int, cache_len: int,
+                         dense: bool = False) -> dict:
+    """One layer's cache; an expert layer (not ``dense``) also keeps the
+    experts each position chose (``route``, int8 expert ids)."""
+    if cfg.moe is not None and not dense and kind != "ssd":
+        c = block_cache_template(cfg, kind, batch, cache_len, dense=True)
+        c["route"] = ParamSpec((batch, cache_len, cfg.moe.top_k),
+                               ("batch", "cache_seq", None), "zeros", dtype="int8")
+        return c
     if kind in ("attn", "local"):
         return attn.cache_template(cfg, kind, batch, cache_len)
+    if kind == "mla":
+        return mla_mod.cache_template(cfg, batch, cache_len)
     if kind == "xdec":
         c = attn.cache_template(cfg, "attn", batch, cache_len)
         hd, kv, F = cfg.resolved_head_dim, cfg.n_kv_heads, cfg.encdec.n_frames
@@ -97,8 +116,20 @@ def block_cache_template(cfg: ModelConfig, kind: str, batch: int, cache_len: int
     raise ValueError(kind)
 
 
+# Rows of the on-device MoE counters carried in the cache: prefill, decode.
+# Columns: held experts that ran (per layer), assignments to held experts,
+# all top-k assignments — each summed over expert layers and steps.
+MOE_COUNTS = ("prefill", "decode")
+
+
 def cache_template(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
     t: dict = {}
+    if cfg.first_k_dense:
+        t["lead"] = {f"l{i}": block_cache_template(cfg, k, batch, cache_len, dense=True)
+                     for i, k in enumerate(cfg.lead_kinds)}
+    if cfg.moe is not None:
+        t["moe_counts"] = ParamSpec((len(MOE_COUNTS), 3), (None, None), "zeros",
+                                    dtype="int32")
     if cfg.n_superblocks > 0:
         t["blocks"] = {
             f"p{i}": stack_specs(
@@ -118,22 +149,32 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> Params:
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=jnp.bfloat16):
-    return spec_map(lambda s: jnp.zeros(s.shape, dtype),
+    return spec_map(lambda s: jnp.zeros(s.shape, s.dtype or dtype),
                     cache_template(cfg, batch, cache_len))
 
 
 # ======================================================================
 # Block forward (train / prefill)
 # ======================================================================
+def _no_counts():
+    return jnp.zeros((3,), jnp.int32)
+
+
 def block_forward_full(cfg: ModelConfig, kind: str, p, x, positions, cache_len,
-                       enc_out=None, enc_pos=None):
-    """Returns (x, aux_loss, cache|None)."""
+                       enc_out=None, enc_pos=None, dense: bool = False):
+    """Returns (x, aux_loss, cache|None, MoE counters int32[3]).  With
+    ``cache_len`` None (training) an expert layer takes the GShard
+    dispatch, else (prefill) the dropless held-expert layer."""
     aux = jnp.zeros((), jnp.float32)
+    counts = _no_counts()
     h = apply_norm(cfg.norm, x, p["norm1"]["scale"], cfg.norm_eps)
     cache = None
     if kind in ("attn", "local"):
         out, cache = attn.prefill_attention(p["attn"], h, positions, cfg, kind,
                                             cache_len=cache_len)
+        x = x + out
+    elif kind == "mla":
+        out, cache = mla_mod.prefill_mla(p["attn"], h, positions, cfg, cache_len)
         x = x + out
     elif kind == "enc":
         q, k, v = attn._project_qkv(p["attn"], h, cfg)
@@ -156,7 +197,7 @@ def block_forward_full(cfg: ModelConfig, kind: str, p, x, positions, cache_len,
             out, cache = ssm_mod.ssd_block_apply(p["ssd"], h, cfg, return_cache=True)
         else:
             out = ssm_mod.ssd_block_apply(p["ssd"], h, cfg)
-        return x + out, aux, cache  # no MLP
+        return x + out, aux, cache, counts  # no MLP
     elif kind == "rglru":
         if cache_len is not None:
             out, cache = rglru_mod.rglru_prefill_cache(p["rglru"], h, cfg)
@@ -167,20 +208,33 @@ def block_forward_full(cfg: ModelConfig, kind: str, p, x, positions, cache_len,
         raise ValueError(kind)
 
     h2 = apply_norm(cfg.norm, x, p["norm2"]["scale"], cfg.norm_eps)
-    if cfg.moe is not None and kind != "enc":
-        mo, aux = moe_mod.moe_ffn(p["mlp"], h2, cfg)
+    if cfg.moe is not None and kind != "enc" and not dense:
+        if cache_len is None:
+            mo, aux = moe_mod.moe_ffn(p["mlp"], h2, cfg)
+        else:
+            mo, counts, chosen = moe_mod.held_ffn_grouped(p["mlp"], h2, cfg)
+            B, S = chosen.shape[:2]
+            cache = dict(cache, route=jnp.pad(chosen.astype(jnp.int8),
+                                              [(0, 0), (0, cache_len - S), (0, 0)]))
         x = x + mo
     else:
         x = x + mlp_apply(p["mlp"], h2, cfg.mlp)
     x = shard(x, "batch", "seq", None)
-    return x, aux, cache
+    return x, aux, cache, counts
 
 
-def block_forward_decode(cfg: ModelConfig, kind: str, p, x, cache, pos):
-    """x: (B,1,D). Returns (x, new_cache)."""
+def block_forward_decode(cfg: ModelConfig, kind: str, p, x, cache, pos,
+                         dense: bool = False, experts=None, layer=None):
+    """x: (B,1,D). Returns (x, new_cache, MoE counters int32[3]).  An
+    expert layer takes its held experts from ``experts`` (stacked over
+    layers where ``layer`` indexes them), not from ``p``."""
+    counts = _no_counts()
     h = apply_norm(cfg.norm, x, p["norm1"]["scale"], cfg.norm_eps)
     if kind in ("attn", "local"):
         out, new_cache = attn.decode_attention(p["attn"], cache, h, pos, cfg, kind)
+        x = x + out
+    elif kind == "mla":
+        out, new_cache = mla_mod.decode_mla(p["attn"], cache, h, pos, cfg)
         x = x + out
     elif kind == "xdec":
         self_cache = {"k": cache["k"], "v": cache["v"]}
@@ -191,7 +245,7 @@ def block_forward_decode(cfg: ModelConfig, kind: str, p, x, cache, pos):
         new_cache = dict(new_self, xk=cache["xk"], xv=cache["xv"])
     elif kind == "ssd":
         out, new_cache = ssm_mod.ssd_decode_step(p["ssd"], cache, h, cfg)
-        return x + out, new_cache
+        return x + out, new_cache, counts
     elif kind == "rglru":
         out, new_cache = rglru_mod.rglru_decode_step(p["rglru"], cache, h, cfg)
         x = x + out
@@ -199,12 +253,15 @@ def block_forward_decode(cfg: ModelConfig, kind: str, p, x, cache, pos):
         raise ValueError(kind)
 
     h2 = apply_norm(cfg.norm, x, p["norm2"]["scale"], cfg.norm_eps)
-    if cfg.moe is not None:
-        mo, _ = moe_mod.moe_ffn(p["mlp"], h2, cfg)
+    if cfg.moe is not None and not dense:
+        mo, counts, chosen = moe_mod.held_ffn_decode(p["mlp"], h2, cfg, experts, layer)
+        write = lambda c, t, s: jax.lax.dynamic_update_slice(c, t, (s, 0))  # noqa: E731
+        new_cache = dict(new_cache, route=jax.vmap(write)(
+            cache["route"], chosen.astype(jnp.int8), pos))
         x = x + mo
     else:
         x = x + mlp_apply(p["mlp"], h2, cfg.mlp)
-    return x, new_cache
+    return x, new_cache, counts
 
 
 def _cross_decode(cfg, p, x, xk, xv):
@@ -226,20 +283,34 @@ def _cross_decode(cfg, p, x, xk, xv):
 # ======================================================================
 def _apply_trunk_full(cfg, params, x, positions, cache_len, enc_out, enc_pos,
                       remat: bool):
+    """Returns (x, aux, caches, MoE counters int32[3])."""
     pattern = tuple(decoder_kind(cfg, k) for k in cfg.pattern)
     aux_total = jnp.zeros((), jnp.float32)
+    counts = _no_counts()
     caches: dict = {}
+
+    lead_caches = {}
+    for i, k in enumerate(cfg.lead_kinds):
+        x, aux, c, _ = block_forward_full(cfg, decoder_kind(cfg, k),
+                                          params["lead"][f"l{i}"], x, positions,
+                                          cache_len, enc_out, enc_pos, dense=True)
+        aux_total = aux_total + aux
+        lead_caches[f"l{i}"] = c
+    if cfg.first_k_dense and cache_len is not None:
+        caches["lead"] = lead_caches
 
     def superblock(x, layer_params):
         aux_sb = jnp.zeros((), jnp.float32)
+        n_sb = _no_counts()
         sb_caches = {}
         for i, kind in enumerate(pattern):
-            x, aux, c = block_forward_full(cfg, kind, layer_params[f"p{i}"], x,
-                                           positions, cache_len, enc_out, enc_pos)
+            x, aux, c, n = block_forward_full(cfg, kind, layer_params[f"p{i}"], x,
+                                              positions, cache_len, enc_out, enc_pos)
             aux_sb = aux_sb + aux
+            n_sb = n_sb + n
             if cache_len is not None:
                 sb_caches[f"p{i}"] = c
-        return x, aux_sb, sb_caches
+        return x, aux_sb, n_sb, sb_caches
 
     if remat:
         superblock = jax.checkpoint(superblock,
@@ -247,72 +318,109 @@ def _apply_trunk_full(cfg, params, x, positions, cache_len, enc_out, enc_pos,
 
     if cfg.n_superblocks > 0:
         def body(carry, layer_params):
-            x, aux = carry
-            x, aux_sb, sb_caches = superblock(x, layer_params)
-            return (x, aux + aux_sb), (sb_caches if cache_len is not None else 0)
+            x, aux, n = carry
+            x, aux_sb, n_sb, sb_caches = superblock(x, layer_params)
+            return (x, aux + aux_sb, n + n_sb), (sb_caches if cache_len is not None else 0)
 
         if runtime_flags.UNROLL_SCANS:
             ys_list = []
             for i in range(cfg.n_superblocks):
                 lp = jax.tree.map(lambda a: a[i], params["blocks"])
-                (x, aux_total), y = body((x, aux_total), lp)
+                (x, aux_total, counts), y = body((x, aux_total, counts), lp)
                 ys_list.append(y)
             ys = jax.tree.map(lambda *zs: jnp.stack(zs), *ys_list) \
                 if cache_len is not None else None
         else:
-            (x, aux_total), ys = jax.lax.scan(body, (x, aux_total), params["blocks"])
+            (x, aux_total, counts), ys = jax.lax.scan(
+                body, (x, aux_total, counts), params["blocks"])
         if cache_len is not None:
             caches["blocks"] = ys
 
     tail_caches = {}
     for i, k in enumerate(cfg.tail_kinds):
         kind = decoder_kind(cfg, k)
-        x, aux, c = block_forward_full(cfg, kind, params["tail"][f"t{i}"], x,
-                                       positions, cache_len, enc_out, enc_pos)
+        x, aux, c, n = block_forward_full(cfg, kind, params["tail"][f"t{i}"], x,
+                                          positions, cache_len, enc_out, enc_pos)
         aux_total = aux_total + aux
+        counts = counts + n
         if cache_len is not None:
             tail_caches[f"t{i}"] = c
     if cache_len is not None:
         caches["tail"] = tail_caches
-    return x, aux_total, caches
+    return x, aux_total, caches, counts
+
+
+def _split_experts(tree: dict):
+    """``(tree without its held-expert leaves, those leaves)``, per
+    pattern position ("p0", ...), for an expert layer's decode."""
+    rest, experts = {}, {}
+    for name, p in tree.items():
+        if "mlp" in p and "experts" in p["mlp"]:
+            experts[name] = p["mlp"]["experts"]
+            p = dict(p, mlp={k: v for k, v in p["mlp"].items() if k != "experts"})
+        rest[name] = p
+    return rest, experts
 
 
 def _apply_trunk_decode(cfg, params, x, cache, pos):
+    """Returns (x, new_cache, MoE counters int32[3]).  Scanned expert
+    layers read their held experts from the whole stacked leaves, by
+    layer index inside each expert's branch, so that the scan slices no
+    expert that the step does not run."""
     pattern = tuple(decoder_kind(cfg, k) for k in cfg.pattern)
+    counts = _no_counts()
+
+    new_lead = {}
+    for i, k in enumerate(cfg.lead_kinds):
+        x, new_lead[f"l{i}"], _ = block_forward_decode(
+            cfg, decoder_kind(cfg, k), params["lead"][f"l{i}"], x,
+            cache["lead"][f"l{i}"], pos, dense=True)
 
     if cfg.n_superblocks > 0:
-        def body(x, xs):
-            layer_params, layer_cache = xs
+        blocks, experts = _split_experts(params["blocks"])
+
+        def body(carry, xs):
+            x, n = carry
+            layer_params, layer_cache, li = xs
             new_caches = {}
             for i, kind in enumerate(pattern):
-                x, nc = block_forward_decode(cfg, kind, layer_params[f"p{i}"],
-                                             x, layer_cache[f"p{i}"], pos)
-                new_caches[f"p{i}"] = nc
-            return x, new_caches
+                name = f"p{i}"
+                x, nc, n_l = block_forward_decode(
+                    cfg, kind, layer_params[name], x, layer_cache[name], pos,
+                    experts=experts.get(name), layer=li)
+                new_caches[name] = nc
+                n = n + n_l
+            return (x, n), new_caches
 
+        layer_ids = jnp.arange(cfg.n_superblocks)
         if runtime_flags.UNROLL_SCANS:
             ys_list = []
             for i in range(cfg.n_superblocks):
-                xs_i = jax.tree.map(lambda a: a[i],
-                                    (params["blocks"], cache["blocks"]))
-                x, y = body(x, xs_i)
+                xs_i = jax.tree.map(lambda a: a[i], (blocks, cache["blocks"]))
+                (x, counts), y = body((x, counts), xs_i + (i,))
                 ys_list.append(y)
             new_blocks = jax.tree.map(lambda *zs: jnp.stack(zs), *ys_list)
         else:
-            x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
+            (x, counts), new_blocks = jax.lax.scan(
+                body, (x, counts), (blocks, cache["blocks"], layer_ids))
     else:
         new_blocks = None
 
+    tail, tail_experts = _split_experts(params["tail"])
     new_tail = {}
     for i, k in enumerate(cfg.tail_kinds):
         kind = decoder_kind(cfg, k)
-        x, nc = block_forward_decode(cfg, kind, params["tail"][f"t{i}"],
-                                     x, cache["tail"][f"t{i}"], pos)
+        x, nc, n = block_forward_decode(cfg, kind, tail[f"t{i}"], x,
+                                        cache["tail"][f"t{i}"], pos,
+                                        experts=tail_experts.get(f"t{i}"))
         new_tail[f"t{i}"] = nc
+        counts = counts + n
     new_cache = {"tail": new_tail}
     if new_blocks is not None:
         new_cache["blocks"] = new_blocks
-    return x, new_cache
+    if cfg.first_k_dense:
+        new_cache["lead"] = new_lead
+    return x, new_cache, counts
 
 
 # ======================================================================
@@ -348,7 +456,7 @@ def _encode(cfg, params, frames):
     x = frames.astype(dt) + sinusoidal_pos(pos, cfg.d_model).astype(dt)
 
     def body(x, layer_params):
-        x, _, _ = block_forward_full(cfg, "enc", layer_params, x, pos, None)
+        x, _, _, _ = block_forward_full(cfg, "enc", layer_params, x, pos, None)
         return x, 0
 
     if runtime_flags.UNROLL_SCANS:
@@ -388,8 +496,8 @@ def forward_train(cfg: ModelConfig, params, batch, remat: bool = False):
     """batch: {'tokens', 'targets', ['image_embeds'|'frames']}.
     Returns (loss fp32, metrics)."""
     x, positions, enc_out, enc_pos = _assemble_input(cfg, params, batch)
-    x, aux, _ = _apply_trunk_full(cfg, params, x, positions, None, enc_out,
-                                  enc_pos, remat)
+    x, aux, _, _ = _apply_trunk_full(cfg, params, x, positions, None, enc_out,
+                                     enc_pos, remat)
     x = apply_norm(cfg.norm, x, params["final_norm"]["scale"], cfg.norm_eps)
     if cfg.vlm is not None:  # predict only over text positions
         x = x[:, -batch["tokens"].shape[1]:]
@@ -409,8 +517,10 @@ def forward_train(cfg: ModelConfig, params, batch, remat: bool = False):
 def prefill(cfg: ModelConfig, params, batch, cache_len: int):
     """Returns (cache, last_token_logits (B, V))."""
     x, positions, enc_out, enc_pos = _assemble_input(cfg, params, batch)
-    x, _, caches = _apply_trunk_full(cfg, params, x, positions, cache_len,
-                                     enc_out, enc_pos, remat=False)
+    x, _, caches, counts = _apply_trunk_full(cfg, params, x, positions, cache_len,
+                                             enc_out, enc_pos, remat=False)
+    if cfg.moe is not None:
+        caches["moe_counts"] = jnp.stack([counts, _no_counts()])
     x = apply_norm(cfg.norm, x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = unembed(cfg, params, x[:, -1:])[:, 0]
     return caches, logits
@@ -421,7 +531,9 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos):
     positions = pos[:, None]
     x = embed_tokens(cfg, params, tokens[:, None], positions)
     x = shard(x, "batch", None, None)
-    x, new_cache = _apply_trunk_decode(cfg, params, x, cache, pos)
+    x, new_cache, counts = _apply_trunk_decode(cfg, params, x, cache, pos)
+    if cfg.moe is not None:
+        new_cache["moe_counts"] = cache["moe_counts"].at[1].add(counts)
     x = apply_norm(cfg.norm, x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = unembed(cfg, params, x)[:, 0]
     return logits, new_cache

@@ -12,7 +12,8 @@ nothing.
 Every span name is listed in :data:`SERVING_SPANS` or
 :data:`ROUTER_SPANS`; call sites use only those names, and readers of a
 trace import the tuples to tell program spans from any others.  Spans are
-never opened inside traced (jitted) code, nor once per row of a batch.
+never opened inside traced (jitted) code, nor once per row of a batch;
+the model's scopes inside jitted code are listed in :data:`MODEL_SCOPES`.
 """
 from __future__ import annotations
 
@@ -27,6 +28,16 @@ SERVING_SPANS = (
     "pool.run",             # one member's prefill and decode steps
     "pool.run.upload",      # the prompt's tokens to the device
     "pool.run.sync",        # the wait for the last step's logits
+    "pool.run.counters",    # an expert member's counters, read after the sync
+)
+
+# Named scopes inside the jitted model code (``jax.named_scope``): they
+# name the device operations of a step in the trace and the HLO metadata.
+MODEL_SCOPES = (
+    "mla.attend",           # latent attention: projections, scores, output
+    "moe.route",            # router scores, top-k choice and gates
+    "moe.held",             # the held experts' part of an expert layer
+    "moe.shared",           # the shared experts
 )
 
 # The router's batch path: ``Router.route_batch_arrays`` down to

@@ -28,9 +28,20 @@ import numpy as np
 from repro.core.netmodel import NetworkModel
 from repro.core.policy import Policy
 from repro.core.profiles import ModelProfile, ProfileStore
+from repro.models.model import MOE_COUNTS
 from repro.obs import span
 from repro.router import AdmissionController, InferenceRequest, Router
 from repro.serving.pool import Variant
+
+# Columns of ``Variant.moe_counts`` (rows: ``model.MOE_COUNTS``).
+MOE_FIELDS = ("experts_ran", "held_assignments", "assignments")
+
+
+def _moe_counts(counts) -> Optional[Dict[str, Dict[str, int]]]:
+    if counts is None:
+        return None
+    return {ph: dict(zip(MOE_FIELDS, map(int, row)))
+            for ph, row in zip(MOE_COUNTS, counts)}
 
 
 @dataclass
@@ -48,6 +59,10 @@ class RequestResult:
     reject_reason: str = ""
     tokens_served: int = 0      # n_decode + 1 when served, 0 when shed
     t_queue_ms: float = 0.0     # arrival -> execute start (0: not given)
+    # An expert member's counters for this request, per phase ("prefill",
+    # "decode"): held experts that ran (summed over expert layers and
+    # steps), top-k assignments to held experts, all top-k assignments.
+    moe_counts: Optional[Dict[str, Dict[str, int]]] = None
 
 
 @dataclass
@@ -167,7 +182,8 @@ class PoolExecutor:
                     t_e2e_ms=e2e, t_sla_ms=t_sla, met_sla=e2e <= t_sla,
                     quality=v.quality, hedged=hedged,
                     w_queue_ms=dec.budget.w_queue_ms,
-                    tokens_served=n_decode + 1, t_queue_ms=t_queue)
+                    tokens_served=n_decode + 1, t_queue_ms=t_queue,
+                    moe_counts=_moe_counts(getattr(v, "moe_counts", None)))
                 self.results.append(res)
         return res
 
@@ -179,9 +195,16 @@ class PoolExecutor:
         served = [r for r in rs if r.admitted]
         usage: Dict[str, int] = {}
         tokens: Dict[str, int] = {}
+        moe: Dict[str, Dict[str, Dict[str, int]]] = {}
         for r in served:
             usage[r.variant] = usage.get(r.variant, 0) + 1
             tokens[r.variant] = tokens.get(r.variant, 0) + r.tokens_served
+            if r.moe_counts:
+                m = moe.setdefault(r.variant, {ph: dict.fromkeys(MOE_FIELDS, 0)
+                                               for ph in r.moe_counts})
+                for ph, c in r.moe_counts.items():
+                    for k in MOE_FIELDS:
+                        m[ph][k] += c[k]
         e2e = [r.t_e2e_ms for r in served]
         return {
             "n": len(rs),
@@ -202,4 +225,13 @@ class PoolExecutor:
             "usage": {k: v / len(served) for k, v in sorted(usage.items())},
             "tokens_served": sum(tokens.values()),
             "tokens_served_by_member": dict(sorted(tokens.items())),
+            # expert members: held experts that ran, per phase, and the
+            # share of top-k assignments that landed on held experts
+            "experts_ran_by_member": {
+                name: {ph: c["experts_ran"] for ph, c in m.items()}
+                for name, m in sorted(moe.items())},
+            "held_assignment_share_by_member": {
+                name: sum(c["held_assignments"] for c in m.values())
+                / max(1, sum(c["assignments"] for c in m.values()))
+                for name, m in sorted(moe.items())},
         }

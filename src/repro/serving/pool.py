@@ -47,6 +47,9 @@ class Variant:
     decode_fn: Callable = None
     cache_len: int = 128
     inflight: int = 0           # requests dispatched but not finished
+    # An expert member's on-device counters from its last run, read once
+    # after the final sync (``model.MOE_COUNTS`` rows); None otherwise.
+    moe_counts: Optional[np.ndarray] = None
 
     def estimated_wait_ms(self, profile) -> float:
         """Queue-wait estimate for one more request on this variant.
@@ -74,7 +77,8 @@ class Variant:
     def run(self, tokens: np.ndarray, n_decode: int = 4) -> float:
         """Execute prefill + n_decode steps; returns wall ms (blocking).
         With ``n_decode == 0`` the prefill alone runs and its logits are
-        waited for."""
+        waited for.  An expert member's counters are read after the
+        wait, into ``moe_counts``."""
         with span("pool.run"):
             t0 = time.perf_counter()
             with span("pool.run.upload"):
@@ -89,7 +93,11 @@ class Variant:
                 pos = pos + 1
             with span("pool.run.sync"):
                 jax.block_until_ready(logits)
-            return (time.perf_counter() - t0) * 1e3
+            ms = (time.perf_counter() - t0) * 1e3
+            if "moe_counts" in cache:
+                with span("pool.run.counters"):
+                    self.moe_counts = np.asarray(cache["moe_counts"])
+            return ms
 
 
 def scaled_family(base: ModelConfig, *, widths=(0.25, 0.5, 1.0),

@@ -85,7 +85,7 @@ def test_full_config_exact(arch):
         "gemma3-4b": (34, 2560, 8, 4, 10240, 262144),
         "whisper-tiny": (4, 384, 6, 6, 1536, 51865),
         "dbrx-132b": (40, 6144, 48, 8, 10752, 100352),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
         "internvl2-2b": (24, 2048, 16, 8, 8192, 92553),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
@@ -96,7 +96,7 @@ def test_full_config_exact(arch):
 def test_moe_configs():
     dbrx = get_config("dbrx-132b").moe
     assert (dbrx.n_experts, dbrx.top_k) == (16, 4)
-    moon = get_config("moonshot-v1-16b-a3b").moe
+    moon = get_config("moonlight-16b-a3b").moe
     assert (moon.n_experts, moon.top_k) == (64, 6)
 
 

@@ -119,3 +119,29 @@ def test_pool_members_compile_and_fit_one_chip(one_chip):
         assert decode.as_text()
         arg_bytes += prefill.memory_analysis().argument_size_in_bytes
     assert arg_bytes < HBM_BYTES
+
+
+def test_moonlight_share_pool_compiles_and_fits_one_chip(one_chip):
+    """qwen2-1.5b beside one chip's share of Moonlight-16B-A3B (8 of its
+    64 experts per layer, latent attention, a leading dense layer) at
+    published widths in bf16: a 2048-token prefill into a 2064-position
+    cache and the decode step compile, and the two members' parameters
+    fit one chip's HBM together."""
+    tokens, cache_len = (1, 2048), 2064
+    arg_bytes = 0
+    for cfg in (get_config("qwen2-1.5b"),
+                get_config("moonlight-16b-a3b").expert_share(8)):
+        params = _on(abstract(M.param_template(cfg), jnp.dtype(cfg.dtype)),
+                     one_chip)
+        tok = _sds(tokens, jnp.int32, one_chip)
+        prefill = pool.prefill_step.lower(cfg, params, tok,
+                                          cache_len=cache_len).compile()
+        cache_shapes, _ = jax.eval_shape(
+            lambda p, t: M.prefill(cfg, p, {"tokens": t}, cache_len),
+            params, tok)
+        vec = _sds((1,), jnp.int32, one_chip)
+        decode = pool.decode_step.lower(
+            cfg, params, _on(cache_shapes, one_chip), vec, vec).compile()
+        assert decode.as_text()
+        arg_bytes += prefill.memory_analysis().argument_size_in_bytes
+    assert arg_bytes < HBM_BYTES
