@@ -251,6 +251,24 @@ class ProfileStore:
         self.step += 1
         self.profiles[name].last_selected = self.step
 
+    def mark_selected_many(self, picks) -> None:
+        """Batched :meth:`mark_selected`: ``picks`` are model positions
+        (the table's order), in selection order.  Leaves the store as
+        ``len(picks)`` ``mark_selected`` calls would: ``step`` advances
+        by the count and each chosen model's ``last_selected`` is the
+        step of its last pick."""
+        picks = np.asarray(picks, dtype=np.int64)
+        k = len(picks)
+        if k == 0:
+            return
+        # First occurrence in the reversed picks == last in row order.
+        chosen, first_rev = np.unique(picks[::-1], return_index=True)
+        names = self.names()
+        for m, step in zip(chosen.tolist(),
+                           (self.step + k - first_rev).tolist()):
+            self.profiles[names[m]].last_selected = step
+        self.step += k
+
     def cold_models(self) -> List[str]:
         """Models whose profile is stale and due a re-probe."""
         return [
@@ -369,6 +387,14 @@ class WindowedProfileStore(ProfileStore):
     def mark_selected(self, name: str) -> None:
         super().mark_selected(name)
         self._present_stale()
+
+    def mark_selected_many(self, picks) -> None:
+        # The presented μ depends only on the final ``step`` and on
+        # ``_seen``, which selections do not touch: one sweep at the end
+        # leaves what a sweep after every pick would.
+        super().mark_selected_many(picks)
+        if len(picks):
+            self._present_stale()
 
     def _present_stale(self) -> None:
         """Sweep the exploration decay: for every model whose last

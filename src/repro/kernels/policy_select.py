@@ -720,6 +720,27 @@ def _charged_pallas(mu, sig, acc, rank, mu_charge, cand_mask, speed,
             out[3].astype(jnp.int32), out[4])
 
 
+def _charged_operands(npad: int, n: int, bpad: int, t_u, t_l, state,
+                      adm_limit):
+    """The charged pass's host operands, as numpy in ``run``'s order
+    after the pool columns: ``(mu_charge, cand_mask, speed, rep_wait,
+    t_u, t_l)`` and, last, the admission limit row (``-inf`` on padded
+    rows, ``+inf`` everywhere real when ``adm_limit`` is ``None``)."""
+    cand_mask = np.zeros((npad, len(state.rep_wait)), dtype=bool)
+    lens = [len(c) for c in state.cand]
+    cand_mask[np.repeat(np.arange(len(lens)), lens),
+              np.concatenate(state.cand)] = True
+    mu_charge = np.zeros(npad, np.float32)
+    mu_charge[:n] = np.asarray(state.mu, np.float64)[:n]
+    lim = np.full(bpad, -np.inf, np.float32)
+    lim[:len(t_u)] = (np.inf if adm_limit is None
+                      else np.asarray(adm_limit, np.float32))
+    return (mu_charge, cand_mask,
+            np.asarray(state.speed, np.float32),
+            np.asarray(state.rep_wait, np.float32),
+            _pad_batch(t_u, bpad), _pad_batch(t_l, bpad), lim)
+
+
 @functools.lru_cache(maxsize=32)
 def _charged_jit(npad: int, gamma: float, slack: float, include_mu: bool,
                  fastest: int, use_kernel: bool = False,
@@ -777,24 +798,9 @@ def charged_select(pool: DevicePool, t_u, t_l, state, *,
     f32 = jnp.float32
 
     with span("router.select.pack"):
-        cand_mask = np.zeros((npad, R), dtype=bool)
-        for m, c in enumerate(state.cand):
-            cand_mask[m, np.asarray(c)] = True
-        mu_charge = np.zeros(npad, np.float32)
-        mu_charge[:n] = np.asarray(state.mu, np.float64)[:n]
-
-        lim = np.full(bpad, -np.inf, np.float32)
-        if adm_limit is None:
-            lim[:B] = np.inf
-        else:
-            lim[:B] = np.asarray(adm_limit, np.float32)
-        args = (pool.mu, pool.sigma, pool.acc, pool.rank,
-                jnp.asarray(mu_charge), jnp.asarray(cand_mask),
-                jnp.asarray(state.speed, f32),
-                jnp.asarray(state.rep_wait, f32),
-                jnp.asarray(_pad_batch(t_u, bpad)),
-                jnp.asarray(_pad_batch(t_l, bpad)))
-        lim_dev = jnp.asarray(lim)
+        host = _charged_operands(npad, n, bpad, t_u, t_l, state, adm_limit)
+        *dev, lim_dev = jax.device_put(host)
+        args = (pool.mu, pool.sigma, pool.acc, pool.rank, *dev)
     with span("router.select.draw"):
         r01 = jax.random.uniform(jax.random.PRNGKey(seed), (bpad,),
                                  dtype=f32)
@@ -804,9 +810,10 @@ def charged_select(pool: DevicePool, t_u, t_l, state, *,
                       bool(adm_include_mu), pool.fastest, kernel,
                       kernel and jax.default_backend() != "tpu")
     with span("router.select.readback"):
-        picks, admitted, has_base, rep, w_chosen = fn(*args, r01, lim_dev)
-        return (np.asarray(picks)[:B], np.asarray(admitted)[:B],
-                np.asarray(has_base)[:B], np.asarray(rep)[:B],
+        # One device_get starts all five copies before waiting on any.
+        picks, admitted, has_base, rep, w_chosen = jax.device_get(
+            fn(*args, r01, lim_dev))
+        return (picks[:B], admitted[:B], has_base[:B], rep[:B],
                 np.asarray(w_chosen, np.float64)[:B])
 
 

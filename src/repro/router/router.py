@@ -276,14 +276,18 @@ class Router:
             t_sla_ms=t_sla, t_input_ms=t_input, rid=i,
             sla_class=sla_class[i] if sla_class is not None else None)
 
+    @staticmethod
+    def _reason_code(res: BatchDecisions, reason: str) -> int:
+        """``reason``'s code in ``res.reasons``, interned on first use."""
+        try:
+            return res.reasons.index(reason)
+        except ValueError:
+            res.reasons.append(reason)
+            return len(res.reasons) - 1
+
     def _shed(self, res: BatchDecisions, i: int, reason: str,
               w_min: float) -> None:
-        try:
-            code = res.reasons.index(reason)
-        except ValueError:
-            code = len(res.reasons)
-            res.reasons.append(reason)
-        res.reject_code[i] = code
+        res.reject_code[i] = self._reason_code(res, reason)
         res.w_queue_ms[i] = w_min
 
     def _route_scalar(self, t_sla, t_input, rng, waits, depth_fn,
@@ -640,22 +644,21 @@ class Router:
         self.n_charged_kernel_batches += policy_select.charged_kernel_engaged(
             pool.npad, len(state.rep_wait))
         picks, admitted, has_base, replica, w_chosen = out
-        names = tab.names
         with span("router.apply"):
-            for i in range(len(budgets)):
-                if not admitted[i]:
-                    self._shed(res, i,
-                               "W_queue exceeds the remaining budget for "
-                               "every model", float(w_chosen[i]))
-                    continue
-                mid = int(picks[i])
-                self.store.mark_selected(names[mid])
-                res.model_idx[i] = mid
-                res.admitted[i] = True
-                res.fallback[i] = not has_base[i]
-                res.w_queue_ms[i] = float(w_chosen[i])
-                if not state.pseudo:
-                    res.replica_idx[i] = int(replica[i])
+            # Shed rows report the pool's minimum wait, admitted rows the
+            # chosen model's: ``w_chosen`` holds whichever applies.
+            res.w_queue_ms[:] = w_chosen
+            shed = ~admitted
+            if shed.any():
+                res.reject_code[shed] = self._reason_code(
+                    res, "W_queue exceeds the remaining budget for every "
+                    "model")
+            res.model_idx[admitted] = picks[admitted]
+            res.admitted[admitted] = True
+            res.fallback[admitted] = ~has_base[admitted]
+            if not state.pseudo:
+                res.replica_idx[admitted] = replica[admitted]
+            self.store.mark_selected_many(picks[admitted])
         self.n_fallback += int((res.admitted & res.fallback).sum())
 
     # ------------------------------------------------------------------

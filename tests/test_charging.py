@@ -434,3 +434,222 @@ def test_charged_kernel_counter(monkeypatch):
     router.reset()
     assert router.stats()["n_scan_batches"] == 0
     assert router.stats()["n_charged_kernel_batches"] == 0
+
+
+# ----------------------------------------------------------------------
+# the charged tick's host side: column write-back, batched bookkeeping,
+# the one-transfer pack
+# ----------------------------------------------------------------------
+
+_SHED_REASON = "W_queue exceeds the remaining budget for every model"
+
+
+def _rowwise_write_back(router, res, out, pseudo):
+    """The per-row write-back the column version replaced, kept as the
+    reference: returns the fallback count it adds."""
+    picks, admitted, has_base, replica, w_chosen = out
+    names = router.store.table().names
+    for i in range(len(picks)):
+        if not admitted[i]:
+            router._shed(res, i, _SHED_REASON, float(w_chosen[i]))
+            continue
+        mid = int(picks[i])
+        router.store.mark_selected(names[mid])
+        res.model_idx[i] = mid
+        res.admitted[i] = True
+        res.fallback[i] = not has_base[i]
+        res.w_queue_ms[i] = float(w_chosen[i])
+        if not pseudo:
+            res.replica_idx[i] = int(replica[i])
+    return int((res.admitted & res.fallback).sum())
+
+
+def _fixed_columns(case, B, n, R):
+    """``charged_select``'s five columns for one write-back case."""
+    rng = np.random.default_rng(len(case))
+    picks = rng.integers(0, n, B).astype(np.int32)
+    picks[:6] = 3                       # one model picked again and again
+    admitted = {"mixed": rng.random(B) > 0.2,
+                "no_sheds": np.ones(B, bool),
+                "none_admitted": np.zeros(B, bool)}[case]
+    has_base = rng.random(B) > 0.3
+    replica = rng.integers(0, R, B).astype(np.int32)
+    w_chosen = rng.uniform(0.0, 80.0, B)
+    return picks, admitted, has_base, replica, w_chosen
+
+
+@pytest.mark.parametrize("pseudo", [False, True], ids=["replicas", "pseudo"])
+@pytest.mark.parametrize("case", ["mixed", "no_sheds", "none_admitted"])
+def test_charged_write_back_matches_rowwise(monkeypatch, case, pseudo):
+    """The column write-back of a charged jax tick leaves the decision
+    columns, the reasons, the router's counts and the store's selection
+    bookkeeping exactly as the per-row loop did: shed and fallback rows,
+    repeated picks of one model, a tick with no sheds (``reasons`` stays
+    ``[""]``), a tick with none admitted; real replicas and pseudo
+    replicas (where ``replica_idx`` stays −1)."""
+    from repro.core.zoo import make_store
+    from repro.kernels import policy_select
+    B, rpm = 64, 2
+    n = len(TABLE2)
+    out = _fixed_columns(case, B, n, n * rpm)
+    monkeypatch.setattr(policy_select, "charged_select",
+                        lambda *a, **k: out)
+
+    def build():
+        store = make_store(TABLE2)
+        store.mark_selected(store.names()[5])   # a prior selection
+        return Router(store, ModiPick(t_threshold=20.0),
+                      admission=SlaAwareAdmission(), queue_aware=True,
+                      trace_detail=False, backend="jax")
+
+    def state(tab):
+        if pseudo:
+            return ChargedWaits.per_model(tab.names, np.zeros(n), tab.mu)
+        return ChargedWaits(np.zeros(n * rpm),
+                            [range(m * rpm, (m + 1) * rpm) for m in range(n)],
+                            np.ones(n * rpm), tab.mu, tab.names)
+
+    router = build()
+    res = router.route_batch_arrays(np.full(B, 300.0), np.full(B, 20.0),
+                                    np.random.default_rng(0),
+                                    charged=state(router.store.table()))
+    assert router.stats()["n_scan_batches"] == 1
+
+    ref = build()
+    want = BatchDecisions.empty(B, ref.store.table().names)
+    n_fallback = _rowwise_write_back(ref, want, out, pseudo)
+
+    for col in ("model_idx", "admitted", "fallback", "replica_idx",
+                "w_queue_ms", "reject_code"):
+        got, exp = getattr(res, col), getattr(want, col)
+        assert got.dtype == exp.dtype, col
+        np.testing.assert_array_equal(got, exp, err_msg=col)
+    assert res.reasons == want.reasons
+    if case == "no_sheds":
+        assert res.reasons == [""]
+    if pseudo or case == "none_admitted":
+        assert (res.replica_idx == -1).all()
+    s = router.stats()
+    n_admitted = int(want.admitted.sum())
+    assert (s["n_admitted"], s["n_shed"], s["n_fallback"]) == (
+        n_admitted, B - n_admitted, n_fallback)
+    assert router.store.step == ref.store.step
+    assert ({m: p.last_selected for m, p in router.store.profiles.items()}
+            == {m: p.last_selected for m, p in ref.store.profiles.items()})
+
+
+def _store_state(store):
+    tab = store.table()
+    return (store.step,
+            {m: (p.last_selected, p.mu, p.var, p.n_obs)
+             for m, p in store.profiles.items()},
+            tab.mu.tolist(), tab.sigma.tolist(), tab.queue_mu.tolist(),
+            tab.fastest)
+
+
+@pytest.mark.parametrize("profile", ["ewma", "window"])
+def test_mark_selected_many_matches_repeated_mark_selected(profile):
+    """``mark_selected_many`` leaves a store as one ``mark_selected`` a
+    pick would: on an empty sequence, and on a long mixed one split into
+    chunks with observations between them.  In the windowed store the
+    chunks push unpicked models past ``stale_after``, so the presented
+    μ and the table move, and must move alike."""
+    from repro.core.zoo import make_store
+    kw = dict(profile=profile, window=8, stale_after=30)
+    batched, looped = make_store(TABLE2, **kw), make_store(TABLE2, **kw)
+    names = batched.names()
+    for s in (batched, looped):
+        s.table()                       # patched in place from here on
+    batched.mark_selected_many(np.zeros(0, np.int64))
+    assert _store_state(batched) == _store_state(looped)
+
+    rng = np.random.default_rng(4)
+    hot = [0, 2, 7]                     # the rest go stale in windowed mode
+    for chunk in range(6):
+        picks = rng.choice(hot, 25 + 10 * chunk)
+        batched.mark_selected_many(picks)
+        for m in picks:
+            looped.mark_selected(names[m])
+        assert _store_state(batched) == _store_state(looped)
+        for m in hot:
+            lat = float(rng.uniform(5, 100))
+            batched.observe(names[m], lat)
+            looped.observe(names[m], lat)
+    if profile == "window":
+        stale = [m for m in names if batched.staleness(m) > 30]
+        assert stale
+        assert any(batched.profiles[m].mu != batched._raw[m][0]
+                   for m in stale)
+
+
+def _rowwise_cand_mask(npad, R, cand):
+    mask = np.zeros((npad, R), dtype=bool)
+    for m, c in enumerate(cand):
+        mask[m, np.asarray(c)] = True
+    return mask
+
+
+@pytest.mark.parametrize("topology", ["overlapping", "zoo"])
+def test_charged_pack_operands(monkeypatch, topology):
+    """The vectorised pack builds the per-model loop's ``cand_mask`` (a
+    replica serving two models; the zoo's 11 models × 16 replicas), and
+    the jitted ``run`` receives the operands it received before the
+    one-transfer upload: same order, shapes, dtypes and values."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import policy_select
+    B = 300
+    if topology == "zoo":
+        tab, state, budgets = _zoo_charged(B, 16, False, seed=5)
+    else:
+        store = _random_store(np.random.default_rng(6), 4)
+        tab = store.table()
+        state = ChargedWaits(np.arange(5) * 10.0,
+                             [[0, 1], [1, 2], [2, 3, 4], [4, 0]],
+                             np.ones(5), tab.mu, tab.names)
+        budgets = np.random.default_rng(7).uniform(50, 400, B)
+    pool = tab.device_pool()
+    R = len(state.rep_wait)
+    bpad = policy_select._bucket(B, 256)
+    t_l = budgets - 20.0
+
+    host = policy_select._charged_operands(pool.npad, pool.n, bpad,
+                                           budgets, t_l, state, budgets)
+    np.testing.assert_array_equal(
+        host[1], _rowwise_cand_mask(pool.npad, R, state.cand))
+
+    mu_charge = np.zeros(pool.npad, np.float32)
+    mu_charge[:pool.n] = np.asarray(state.mu, np.float64)[:pool.n]
+    lim = np.full(bpad, -np.inf, np.float32)
+    lim[:B] = np.asarray(budgets, np.float32)
+    f32 = jnp.float32
+    want = (pool.mu, pool.sigma, pool.acc, pool.rank,
+            jnp.asarray(mu_charge),
+            jnp.asarray(_rowwise_cand_mask(pool.npad, R, state.cand)),
+            jnp.asarray(state.speed, f32), jnp.asarray(state.rep_wait, f32),
+            jnp.asarray(policy_select._pad_batch(budgets, bpad)),
+            jnp.asarray(policy_select._pad_batch(t_l, bpad)))
+    seen = []
+    real_jit = policy_select._charged_jit
+
+    def spy(*key):
+        fn = real_jit(*key)
+
+        def run(*args):
+            seen.append(args)
+            return fn(*args)
+        return run
+    monkeypatch.setattr(policy_select, "_charged_jit", spy)
+    policy_select.charged_select(pool, budgets, t_l, state, seed=3,
+                                 adm_limit=budgets)
+    (args,) = seen
+    assert len(args) == len(want) + 2
+    r01 = args[len(want)]
+    assert (r01.shape, r01.dtype) == ((bpad,), f32)
+    for i, (got, exp) in enumerate(zip(args, want + (r01,
+                                                       jnp.asarray(lim)))):
+        assert isinstance(got, jax.Array), i
+        assert (got.shape, got.dtype, got.weak_type) == (
+            exp.shape, exp.dtype, exp.weak_type), i
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp),
+                                      err_msg=str(i))
