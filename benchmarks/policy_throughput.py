@@ -1,5 +1,5 @@
 """Selection-throughput microbenchmark: scalar loop vs numpy-batched vs
-jitted/Pallas ModiPick on the Table-2 zoo.
+jitted fused ModiPick on the Table-2 zoo.
 
 The paper puts selection on the hot path of every inference (§3.3), so
 selections/sec bounds how much traffic one router can carry and how big

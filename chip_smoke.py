@@ -15,9 +15,10 @@ One chip runs, in order and in this one process:
 4. serve — 32 requests through ``launch.serve.serve`` (queue-aware
    ``PoolExecutor`` → ``Router`` → ModiPick) over the campus-WiFi uplink
    with a seeded 150–600 ms SLA mix;
-5. selection — the fused Pallas selection at batch 4096 and 100k, its
-   masks and probabilities against their references, and the charged
-   routing path (the Pallas charged kernel) once at 4096.
+5. selection — the fused selection at batch 4096 and 100k, its masks
+   against the numpy reference and its picks inside the eligible sets,
+   and the charged routing path (the Pallas charged kernel) once at
+   4096.
 
 ``--four-chips`` runs only the fleet selection sharded over a 4-device
 ``cell`` mesh, against the single-device call.
@@ -48,7 +49,7 @@ from repro.core.policy import ModiPick  # noqa: E402
 from repro.core.policy_vec import modipick_masks  # noqa: E402
 from repro.core.profiles import ProfileTable  # noqa: E402
 from repro.core.zoo import TABLE2, make_store  # noqa: E402
-from repro.kernels import ops, policy_select, ref  # noqa: E402
+from repro.kernels import policy_select  # noqa: E402
 from repro.launch import serve  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.router import Router, SlaAwareAdmission  # noqa: E402
@@ -75,10 +76,6 @@ FLEET_DEVICES, FLEET_CELLS, FLEET_BATCH = 4, 8, 4096
 # gives 3.5e-2 to 5.9e-2, a wrong decode position 0.16 to 0.26, and an
 # empty cache 1.3.  The bound sits between the first two.
 CACHE_TOL = 2.5e-2
-
-# modipick_probs (Pallas, float32) against kernels/ref.py (jnp, float32):
-# the same formula; only the division and power may differ by a few ulp.
-PROBS_RTOL = PROBS_ATOL = 2e-5
 
 
 def say(phase: str, msg: str) -> None:
@@ -238,8 +235,8 @@ def _budget_columns(B: int, seed: int):
 
 def phase_selection(batches=SELECT_BATCHES, charged_batch=CHARGED_BATCH, *,
                     seed: int = SEED):
-    """The fused selection, its masks and stage-3 kernel against their
-    references, and the charged routing path."""
+    """The fused selection, its masks against the numpy reference and
+    its picks inside the eligible sets, and the charged routing path."""
     on_tpu = jax.default_backend() == "tpu"
     tab = _grid_table()
     pool = tab.device_pool()
@@ -266,28 +263,10 @@ def phase_selection(batches=SELECT_BATCHES, charged_batch=CHARGED_BATCH, *,
             raise RuntimeError(f"batch {B}: fused picks off the "
                                "eligible sets")
 
-        bpad = policy_select._bucket(B, 256)
-        fn = policy_select._fused_jit(pool.npad, 1.0, 256, on_tpu)
-        hlo = fn.lower(pool.mu, pool.sigma, pool.acc, pool.rank,
-                       jnp.zeros(bpad, jnp.float32),
-                       jnp.zeros(bpad, jnp.float32),
-                       np.uint32(seed)).compile().as_text()
-        kernel = "tpu_custom_call" in hlo
-        if on_tpu and not kernel:
-            raise RuntimeError(f"batch {B}: no tpu_custom_call in the "
-                               "fused program — the Pallas kernel did "
-                               "not run")
-
-        args = [x[:pool.n] for x in (pool.mu, pool.sigma, pool.acc)] + [
-            jnp.asarray(x, jnp.float32) for x in (t_u, t_l, eligible)]
-        np.testing.assert_allclose(
-            np.asarray(ops.modipick_probs(*args)),
-            np.asarray(ref.policy_probs_ref(*args)),
-            rtol=PROBS_RTOL, atol=PROBS_ATOL)
-        say("select", f"batch {B} (padded {bpad}): masks equal numpy, "
-                      f"{int(has_base.sum())} with a base, picks eligible, "
-                      f"tpu_custom_call={kernel}, probs match ref within "
-                      f"{PROBS_RTOL:g}; first call {first_s:.3f}s")
+        say("select", f"batch {B} (padded "
+                      f"{policy_select._bucket(B, 256)}): masks equal numpy, "
+                      f"{int(has_base.sum())} with a base, picks eligible; "
+                      f"first call {first_s:.3f}s")
 
     store = make_store(TABLE2)
     router = Router(store, ModiPick(THRESHOLD_MS),

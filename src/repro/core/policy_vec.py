@@ -22,19 +22,18 @@ Backends
 ``select_batch(..., backend=...)`` accepts:
 
 - ``"numpy"`` — the reference implementation, always available;
-- ``"jax"``   — ModiPick's stage-3 utilities + sampling run jitted, with
-  the fused eligibility-mask/utility/normalize step as a Pallas kernel
-  (``repro.kernels.policy_select``; interpret mode off-TPU);
+- ``"jax"``   — ModiPick's stages 1–3 and the categorical draw run as
+  one jitted device pass (``repro.kernels.policy_select.select_fused``);
+  detailed traces still come from the numpy stages, which they need for
+  ``base``/``eligible`` anyway;
 - ``"auto"``/``None`` — numpy below ``JAX_MIN_BATCH`` requests, jax at or
   above it (only for ModiPick — everything else is pure masked
   argmax/argmin, which numpy already does at memory bandwidth).
 
-``REPRO_POLICY_BACKEND`` (env) overrides the default for a whole run —
-set ``numpy`` to force the reference path, ``jax`` to force the kernel.
+:func:`resolve_backend` is the one place that choice is made.
 """
 from __future__ import annotations
 
-import os
 from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -45,10 +44,9 @@ from repro.core.policy import (EPS, DynamicGreedy, ModiPick, Policy,
 from repro.core.profiles import ProfileStore, ProfileTable
 
 # Batch size at which ModiPick's selection moves to the fused jitted
-# path.  Re-tuned for the device-resident stages 1–3 pipeline: on this
-# host's CPU the fused jit crosses numpy between 2k and 8k requests
-# (see BENCH_policy_throughput.json); on TPU the Pallas path wins far
-# earlier, but 4096 keeps the switch conservative everywhere.
+# path.  The value is a CPU crossover (the fused jit passes numpy
+# between 2k and 8k requests there, BENCH_policy_throughput.json); no
+# on-chip cell measures a batch below it.
 JAX_MIN_BATCH = 4096
 
 VALID_BACKENDS = ("auto", "numpy", "jax")
@@ -58,34 +56,20 @@ def _as_table(store: Union[ProfileStore, ProfileTable]) -> ProfileTable:
     return store if isinstance(store, ProfileTable) else store.table()
 
 
-def _resolve_backend(backend: Optional[str], n_batch: int) -> str:
+def resolve_backend(backend: Optional[str], n_batch: int) -> str:
+    """The policy backend a batch of ``n_batch`` requests runs on:
+    ``backend`` as given, or for ``auto``/``None`` jax from
+    ``JAX_MIN_BATCH`` requests up and numpy below.  The Router asks the
+    same question to decide whether a charged batch rides the device
+    pass in ``kernels.policy_select.charged_select``."""
     if backend is None:
-        env = os.environ.get("REPRO_POLICY_BACKEND")
-        if env and env not in VALID_BACKENDS:
-            raise ValueError(
-                f"REPRO_POLICY_BACKEND={env!r} is not a recognised policy "
-                f"backend; valid values: {', '.join(VALID_BACKENDS)}")
-        backend = env or "auto"
+        backend = "auto"
     elif backend not in VALID_BACKENDS:
         raise ValueError(f"unknown policy backend {backend!r}; "
                          f"valid values: {', '.join(VALID_BACKENDS)}")
     if backend == "auto":
-        # The fused device pipeline (stages 1–3 under one jit, Pallas
-        # stage 3 on TPU / plain XLA elsewhere) beats numpy above the
-        # measured crossover on CPU as well as TPU, so auto engages it
-        # at every backend — no interpret-mode Pallas is left on this
-        # path (see BENCH_policy_throughput.json).
         return "jax" if n_batch >= JAX_MIN_BATCH else "numpy"
     return backend
-
-
-def resolve_backend(backend: Optional[str], n_batch: int) -> str:
-    """Public backend resolution (``auto``/env/threshold → ``numpy`` or
-    ``jax``) — the Router uses it to decide whether a charged batch can
-    ride the device-resident charged pass in
-    ``kernels.policy_select.charged_select`` under the same policy as
-    the uncharged fused pipeline."""
-    return _resolve_backend(backend, n_batch)
 
 
 # ----------------------------------------------------------------------
@@ -173,9 +157,8 @@ def _modipick_batch(policy: ModiPick, tab: ProfileTable,
     device-resident under one jit (``kernels.policy_select.select_fused``)
     and ``base``/``eligible``/``probs`` come back None: nothing but the
     budget rows crosses to the device and nothing but the sampled
-    indices crosses back.  ``need_stages=True`` (detailed traces) keeps
-    the host mask path; ``probs`` is None whenever the device samples
-    without materialising the probability matrix host-side."""
+    indices crosses back.  Otherwise (the numpy backend, or detailed
+    traces) the numpy stages run and sample with Gumbel-top-1."""
     t_u = t_budgets
     t_l = t_u - policy.t_threshold
     if backend == "jax" and not need_stages:
@@ -185,17 +168,8 @@ def _modipick_batch(policy: ModiPick, tab: ProfileTable,
             seed=int(rng.integers(np.iinfo(np.int64).max)))
         return idx, has_base, None, None, None
     base, has_base, eligible, _ = modipick_masks(tab, t_u, t_l)
-    probs = None
-    if backend == "jax":
-        from repro.kernels import policy_select
-        choice = policy_select.sample_batch(
-            tab.mu, tab.sigma, tab.accuracy, t_u, t_l, eligible,
-            gamma=policy.gamma,
-            seed=int(rng.integers(np.iinfo(np.int64).max)))
-        choice = np.asarray(choice)
-    else:
-        probs = modipick_probs(tab, t_u, t_l, eligible, policy.gamma)
-        choice = gumbel_top1(probs, rng)
+    probs = modipick_probs(tab, t_u, t_l, eligible, policy.gamma)
+    choice = gumbel_top1(probs, rng)
     return np.where(has_base, choice, tab.fastest), has_base, base, \
         eligible, probs
 
@@ -237,6 +211,25 @@ def _dynamic_greedy_batch(tab: ProfileTable, t_budgets: np.ndarray):
     return np.where(has, order[elig.argmax(axis=1)], tab.fastest), has
 
 
+def _is_scalar(store, t: np.ndarray) -> bool:
+    return len(t) == 1 and isinstance(store, ProfileStore)
+
+
+def _select_one(policy: Policy, store: ProfileStore, t: np.ndarray,
+                rng: np.random.Generator, *, detail: bool
+                ) -> SelectionTrace:
+    """A batch of one IS a scalar selection, whatever the (already
+    validated) backend says — backends shape batches of two or more; the
+    Router routes singletons the same way.  Without ``detail`` the lean
+    scalar core runs (identical pick and RNG stream to
+    ``select_traced``, minus the trace materialisation).  Stochastic
+    policies therefore consume the scalar RNG pattern here, not the
+    batched one — same law, different stream, exactly like the Router's
+    singleton path."""
+    select = policy.select_traced if detail else policy.select_lean
+    return select(store, float(t[0]), rng)
+
+
 def select_batch(policy: Policy, store: Union[ProfileStore, ProfileTable],
                  t_budgets: Sequence[float], rng: np.random.Generator, *,
                  backend: Optional[str] = None) -> List[str]:
@@ -251,19 +244,9 @@ def select_batch(policy: Policy, store: Union[ProfileStore, ProfileTable],
     t = np.asarray(t_budgets, dtype=np.float64)
     if t.ndim != 1:
         raise ValueError("t_budgets must be one-dimensional")
-    backend = _resolve_backend(backend, len(t))
-    if len(t) == 1 and isinstance(store, ProfileStore):
-        # A batch of one IS a scalar selection, whatever the (already
-        # validated) backend says — backends shape batches of two or
-        # more; the Router routes singletons the same way.  ModiPick
-        # rides the lean scalar core (identical picks and RNG stream to
-        # ``select_traced``, minus the trace materialisation); stochastic
-        # policies therefore consume the scalar RNG pattern here, not
-        # the batched one — same law, different stream, exactly like the
-        # Router's singleton path.
-        if type(policy) is ModiPick:
-            return [policy.select_lean(store, float(t[0]), rng).chosen]
-        return [policy.select(store, float(t[0]), rng)]
+    backend = resolve_backend(backend, len(t))
+    if _is_scalar(store, t):
+        return [_select_one(policy, store, t, rng, detail=False).chosen]
 
     # Exact-type dispatch: a subclass may override any stage, so only
     # the classes implemented here take the batched path — everything
@@ -343,11 +326,11 @@ def select_batch_traced(policy: Policy,
                         detail: bool = True) -> List[SelectionTrace]:
     """Batched ``policy.select_traced``: one :class:`SelectionTrace` per
     budget, produced by the same batched stages as :func:`select_batch`
-    (identical picks for identical ``rng`` state).  ModiPick-family
-    traces carry base/eligible/probs (probs only on the numpy backend);
-    greedy traces carry the fallback flag.  ``detail=False`` returns
-    chosen + fallback only — same picks, no per-request stage-tuple
-    materialization (the event-loop hot path).
+    (identical picks for identical ``rng`` state; a batch of one takes
+    the same scalar rule).  ModiPick-family traces carry
+    base/eligible/probs; greedy traces carry the fallback flag.
+    ``detail=False`` returns chosen + fallback only — same picks, no
+    per-request stage-tuple materialization (the event-loop hot path).
     """
     tab = _as_table(store)
     t = np.asarray(t_budgets, dtype=np.float64)
@@ -355,7 +338,9 @@ def select_batch_traced(policy: Policy,
         raise ValueError("t_budgets must be one-dimensional")
     if not len(t):
         return []
-    backend = _resolve_backend(backend, len(t))
+    backend = resolve_backend(backend, len(t))
+    if _is_scalar(store, t):
+        return [_select_one(policy, store, t, rng, detail=detail)]
 
     kind = type(policy)
     if kind is ModiPick:

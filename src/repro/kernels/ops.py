@@ -9,11 +9,9 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
-from repro.kernels import policy_select as _ps
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import ssd_scan as _ssd
 
@@ -50,11 +48,3 @@ def rglru_scan(a, b, *, block_s: int = 256):
     """Linear recurrence over (B,S,W)."""
     return _rg.rglru_scan(a, b, block_s=block_s, interpret=not _on_tpu())
 
-
-@functools.partial(jax.jit, static_argnames=("gamma", "block_b"))
-def modipick_probs(mu, sigma, acc, t_u, t_l, elig, *, gamma: float = 1.0,
-                   block_b: int = 256):
-    """Fused ModiPick stage-3: mu/sigma/acc (n,); t_u/t_l (B,);
-    elig (B,n) → (B,n) probability rows."""
-    return _ps.modipick_probs(mu, sigma, acc, t_u, t_l, elig, gamma=gamma,
-                              block_b=block_b, interpret=not _on_tpu())

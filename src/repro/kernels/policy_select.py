@@ -1,40 +1,34 @@
-"""Device-resident ModiPick selection: fused stages 1–3 under one jit.
+"""Device-resident ModiPick selection.
 
-Three layers live here:
+One stage 1–3 body serves every device path: :func:`_stages12` (the
+Eq. 2 eligibility matrix, the accuracy-order masked argmin and the
+window-membership mask), :func:`_utilities` (the Eq. 3–4 utility rows)
+and :func:`_draw` (the inverse-CDF categorical pick).  They take the
+pool as ``(rows, npad)`` μ/σ rows: a shared pool passes its columns as
+one ``(1, npad)`` row that broadcasts over the batch, a classed batch
+passes one row per request.  Two layers run that body:
 
-- the **stage-3 Pallas TPU kernel** (``_probs_kernel`` /
-  ``modipick_probs``): the fused eligibility-mask / Eq. 3–4 utility /
-  normalize pass over the (batch × pool) matrix.  The pool rides the
-  128-lane axis (padded), the batch is blocked on the sublane axis, and
-  each grid step produces the per-request probability rows for its batch
-  block in one VPU pass — no intermediate (B, n) utility matrix ever
-  round-trips through HBM.
-- the **fused selection pipeline** (``select_fused``): stages 1–2 — the
-  Eq. 2 eligibility matrix, the accuracy-order masked argmax and the
-  window-membership mask — computed in jitted jnp on device, feeding the
-  stage-3 utilities (the Pallas kernel on TPU, the identical jnp math
-  elsewhere) and an inverse-CDF categorical draw, all under ONE jit.
-  Input is ``(mu, sigma, acc, t_u, t_l)``; output is the sampled pool
-  indices.  Nothing round-trips through the host between stages.
-- the **charged sequential-greedy pass** (``charged_select``): the same
-  stages per request against a per-replica wait ledger that every
+- the **fused selection** under one jit, from ``(mu, sigma, acc, t_u,
+  t_l)`` straight to sampled pool indices with nothing crossing to the
+  host between stages: :func:`select_fused` over one pool,
+  :func:`select_fleet_stacked` over a leading cell axis,
+  :func:`select_classed` over per-request class rows;
+- the **charged sequential-greedy pass** (:func:`charged_select`): the
+  same stages per request against a per-replica wait ledger that every
   admitted pick charges — one Pallas kernel on the TPU
-  (``_charged_kernel``), a ``lax.scan`` over ``_charged_step``
+  (``_charged_kernel``), a ``lax.scan`` over :func:`_charged_step`
   elsewhere.
 
-Compiled callables are cached per ``(pool_size, gamma, batch_block)``
+Compiled callables are cached per ``(pool_size, gamma, ...)``
 (``functools.lru_cache`` over the jit closure; XLA's own cache handles
 the bucketed batch shapes), and the pool-side operands are padded to the
 128-lane axis ONCE per :class:`DevicePool` — built at ``ProfileTable``
-freeze via ``ProfileTable.device_pool()`` — instead of per call.  That
-is what turned the historical 1.9 ms batch-1 dispatch into a plain jit
-call.
+freeze via ``ProfileTable.device_pool()`` — instead of per call.
 
 Sampling uses the inverse-CDF trick (one uniform per request against
 the cumulative utility row) instead of per-lane Gumbel noise: exactly
 categorical, and it draws B random numbers instead of B × 128.
-``sample_batch`` keeps the original Gumbel-top-1 kernel wrapper for
-oracle tests; ``kernels.ref`` holds the pure-jnp references.
+``kernels.ref`` holds the pure-jnp stage 1–2 reference.
 """
 from __future__ import annotations
 
@@ -57,92 +51,6 @@ PAD_MU = 1e30
 PAD_RANK = 1e9
 
 
-def _probs_kernel(mu_ref, sig_ref, acc_ref, tu_ref, tl_ref, elig_ref,
-                  out_ref, *, gamma: float):
-    mu = mu_ref[...]          # (1, n)
-    sig = sig_ref[...]
-    acc = acc_ref[...]
-    tu = tu_ref[...]          # (bb, 1)
-    tl = tl_ref[...]
-    e = elig_ref[...]         # (bb, n) 0/1 mask
-
-    num = tu - (mu + sig)                      # broadcast → (bb, n)
-    den = jnp.maximum(jnp.abs(tl - mu), EPS)
-    u = jnp.power(jnp.maximum(acc, EPS), gamma) * num / den
-    u = jnp.where(e > 0, u, 0.0)
-    total = jnp.sum(u, axis=1, keepdims=True)
-    cnt = jnp.sum(e, axis=1, keepdims=True)
-    good = jnp.isfinite(total) & (total > 0)
-    uniform = e / jnp.maximum(cnt, 1.0)
-    out_ref[...] = jnp.where(good, u / jnp.where(good, total, 1.0), uniform)
-
-
-def modipick_probs(mu, sigma, acc, t_u, t_l, elig, *, gamma: float = 1.0,
-                   block_b: int = 256, interpret: bool = False):
-    """Fused stage-3 probability matrix.
-
-    mu/sigma/acc: (n,) pool arrays; t_u/t_l: (B,) per-request bounds;
-    elig: (B, n) stage-2 eligibility → (B, n) float32 probabilities
-    (rows with no eligible model come back all-zero).
-    """
-    B, n = elig.shape
-    npad = max(LANES, -(-n // LANES) * LANES)
-    bb = min(block_b, max(8, -(-B // 8) * 8))
-    bpad = -(-B // bb) * bb
-
-    f32 = jnp.float32
-    pool = lambda x: jnp.pad(jnp.asarray(x, f32), (0, npad - n))[None, :]
-    per_req = lambda x: jnp.pad(jnp.asarray(x, f32), (0, bpad - B))[:, None]
-    e = jnp.pad(jnp.asarray(elig, f32), ((0, bpad - B), (0, npad - n)))
-
-    out = pl.pallas_call(
-        functools.partial(_probs_kernel, gamma=gamma),
-        grid=(bpad // bb,),
-        in_specs=[
-            pl.BlockSpec((1, npad), lambda i: (0, 0)),
-            pl.BlockSpec((1, npad), lambda i: (0, 0)),
-            pl.BlockSpec((1, npad), lambda i: (0, 0)),
-            pl.BlockSpec((bb, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bb, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bb, npad), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, npad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bpad, npad), f32),
-        interpret=interpret,
-    )(pool(mu), pool(sigma), pool(acc), per_req(t_u), per_req(t_l), e)
-    return out[:B, :n]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("gamma", "block_b", "interpret"))
-def _sample_jit(mu, sigma, acc, t_u, t_l, elig, key, *, gamma, block_b,
-                interpret):
-    probs = modipick_probs(mu, sigma, acc, t_u, t_l, elig, gamma=gamma,
-                           block_b=block_b, interpret=interpret)
-    g = jax.random.gumbel(key, probs.shape, dtype=probs.dtype)
-    logits = jnp.where(probs > 0, jnp.log(probs), -jnp.inf)
-    return jnp.argmax(logits + g, axis=1)
-
-
-def sample_batch(mu, sigma, acc, t_u, t_l, elig, *, gamma: float = 1.0,
-                 seed: int = 0, block_b: int = 256) -> np.ndarray:
-    """One Gumbel-top-1 pick per request from the kernel's probability
-    rows; returns (B,) pool indices as numpy.  Rows with no eligible
-    model return an arbitrary index — callers mask them with their
-    fallback (``policy_vec`` routes those to the fastest model)."""
-    interpret = jax.default_backend() != "tpu"
-    key = jax.random.PRNGKey(seed)
-    idx = _sample_jit(jnp.asarray(mu, jnp.float32),
-                      jnp.asarray(sigma, jnp.float32),
-                      jnp.asarray(acc, jnp.float32),
-                      jnp.asarray(t_u, jnp.float32),
-                      jnp.asarray(t_l, jnp.float32),
-                      jnp.asarray(elig, jnp.float32),
-                      key, gamma=gamma, block_b=block_b,
-                      interpret=interpret)
-    return np.asarray(idx)
-
-
 # ======================================================================
 # Device-resident stages 1–3: one jit from (mu, sigma, acc, t_u, t_l)
 # straight to sampled pool indices.
@@ -159,8 +67,9 @@ class DevicePool:
     row.  Padded lanes carry ``PAD_MU``/``PAD_RANK`` sentinels, which
     keeps every stage's math finite without a separate validity mask.
 
-    The 128-lane padding is a TPU tiling constraint (the Pallas stage-3
-    kernel rides the lane axis); the XLA-CPU path has no such
+    The 128-lane padding is a TPU tiling constraint (the pool rides the
+    vreg lane axis, and the charged kernel's rows with it); the XLA-CPU
+    path has no such
     constraint, so off-TPU the pool keeps its natural width instead of
     paying 16× elementwise waste on a typical 8-model zoo.
     """
@@ -191,31 +100,34 @@ class DevicePool:
 
 
 def _stages12(mu, sig, rank, t_u, t_l):
-    """Stages 1–2 on device.  mu/sig/rank: (npad,); t_u/t_l: (B,).
-    Returns ``(base, has_base, eligible)`` — the Eq. 2 eligibility matrix
+    """Stages 1–2 on device.  mu/sig: (1, npad) shared or (B, npad)
+    per-request pool rows; rank: (npad,); t_u/t_l: (B,).  Returns
+    ``(base, has_base, eligible)`` — the Eq. 2 eligibility matrix
     reduced by accuracy-order masked argmin (stage 1) and the window
     membership mask with the base forced in (stage 2)."""
     tu, tl = t_u[:, None], t_l[:, None]
-    mus = (mu + sig)[None, :]
-    elig1 = (mus < tu) & ((mu - sig)[None, :] < tl)          # Eq. 2, (B, npad)
+    mus = mu + sig
+    elig1 = (mus < tu) & ((mu - sig) < tl)                   # Eq. 2, (B, npad)
     has_base = elig1.any(axis=1)
     base = jnp.argmin(jnp.where(elig1, rank[None, :], PAD_RANK + 1.0),
                       axis=1).astype(jnp.int32)              # first in acc order
-    half = jnp.abs(t_l - mu[base]) + sig[base]               # (B,)
+    at_base = lambda x: jnp.take_along_axis(
+        jnp.broadcast_to(x, elig1.shape), base[:, None], axis=1)[:, 0]
+    half = jnp.abs(t_l - at_base(mu)) + at_base(sig)         # (B,)
     lo, hi = (t_l - half)[:, None], (t_l + half)[:, None]
-    natural = (lo <= mu[None, :]) & (mu[None, :] <= hi) & (mus < tu)
-    eligible = natural | (jnp.arange(mu.shape[0])[None, :] == base[:, None])
+    natural = (lo <= mu) & (mu <= hi) & (mus < tu)
+    eligible = natural | (jnp.arange(mu.shape[1])[None, :] == base[:, None])
     eligible &= has_base[:, None]
     return base, has_base, eligible
 
 
 def _utilities(mu, sig, acc, t_u, t_l, eligible, gamma):
-    """Eq. 3–4 utility rows (plain jnp, identical math to the Pallas
-    kernel); degenerate rows (non-finite or non-positive mass) fall back
-    to uniform-over-eligible, exactly like the scalar path."""
+    """Eq. 3–4 utility rows over the same pool rows as :func:`_stages12`
+    (acc: (npad,)); degenerate rows (non-finite or non-positive mass)
+    fall back to uniform-over-eligible, exactly like the scalar path."""
     tu, tl = t_u[:, None], t_l[:, None]
-    num = tu - (mu + sig)[None, :]
-    den = jnp.maximum(jnp.abs(tl - mu[None, :]), EPS)
+    num = tu - (mu + sig)
+    den = jnp.maximum(jnp.abs(tl - mu), EPS)
     u = jnp.power(jnp.maximum(acc, EPS), gamma)[None, :] * num / den
     u = jnp.where(eligible, u, 0.0)
     total = jnp.sum(u, axis=1, keepdims=True)
@@ -223,41 +135,34 @@ def _utilities(mu, sig, acc, t_u, t_l, eligible, gamma):
     return jnp.where(good, u, eligible.astype(u.dtype))
 
 
-def _fused_select(mu, sig, acc, rank, t_u, t_l, seed, *, gamma: float,
-                  block_b: int, use_pallas: bool):
-    """The whole pipeline under one trace: stages 1–2, stage-3 utility
-    rows (Pallas kernel on TPU, jnp elsewhere), inverse-CDF categorical
-    draw.  Returns (B,) int32: the sampled pool index, or -1 where no
-    base model exists (the caller's fallback lane)."""
-    base, has_base, eligible = _stages12(mu, sig, rank, t_u, t_l)
-    if use_pallas:
-        w = modipick_probs(mu, sig, acc, t_u, t_l,
-                           eligible.astype(jnp.float32), gamma=gamma,
-                           block_b=block_b)
-    else:
-        w = _utilities(mu, sig, acc, t_u, t_l, eligible, gamma)
+def _draw(w, base, has_base, r01, fallback):
+    """Inverse-CDF pick from (B, npad) weight rows with one uniform
+    ``r01`` per row: the first index whose cumulative mass exceeds
+    ``r01 · total`` — exact categorical sampling without per-lane noise.
+    Zero-weight lanes have flat cdf segments and are never picked; the
+    float edge ``thresh == total`` goes to the (always eligible) base,
+    and rows without a base go to ``fallback``."""
     cdf = jnp.cumsum(w, axis=1)
     total = cdf[:, -1]
-    r01 = jax.random.uniform(jax.random.PRNGKey(seed), total.shape,
-                             dtype=cdf.dtype)
     thresh = r01 * total
-    # First index whose cumulative mass exceeds the threshold — exact
-    # categorical sampling with ONE uniform per request (no per-lane
-    # noise).  Zero-probability lanes have flat cdf segments and are
-    # never selected; the float edge thresh == total falls back to the
-    # (always eligible) base.
     choice = jnp.argmax(cdf > thresh[:, None], axis=1).astype(jnp.int32)
     choice = jnp.where(total > thresh, choice, base)
-    return jnp.where(has_base, choice, -1)
+    return jnp.where(has_base, choice, fallback)
+
+
+def _fused_select(mu, sig, acc, rank, t_u, t_l, seed, *, gamma: float):
+    """The whole pipeline under one trace (:func:`fleet_select_body`,
+    keyed by ``seed``): (B,) int32 sampled pool indices, -1 where no
+    base model exists (the caller's fallback lane)."""
+    return fleet_select_body(mu, sig, acc, rank, t_u, t_l,
+                             jax.random.PRNGKey(seed), gamma=gamma)
 
 
 @functools.lru_cache(maxsize=64)
-def _fused_jit(npad: int, gamma: float, block_b: int, use_pallas: bool):
-    """The jit cache: one compiled callable per (pool_size, gamma,
-    batch_block) — XLA's shape cache handles the bucketed batch axis."""
-    return jax.jit(functools.partial(_fused_select, gamma=gamma,
-                                     block_b=block_b,
-                                     use_pallas=use_pallas))
+def _fused_jit(npad: int, gamma: float):
+    """The jit cache: one compiled callable per (pool_size, gamma) —
+    XLA's shape cache handles the bucketed batch axis."""
+    return jax.jit(functools.partial(_fused_select, gamma=gamma))
 
 
 @functools.lru_cache(maxsize=8)
@@ -290,8 +195,7 @@ def select_fused(pool: DevicePool, t_u, t_l, *, gamma: float = 1.0,
     transfer (the packed picks)."""
     B = len(t_u)
     bpad = _bucket(B, block_b)
-    fn = _fused_jit(pool.npad, float(gamma), block_b,
-                    jax.default_backend() == "tpu")
+    fn = _fused_jit(pool.npad, float(gamma))
     out = np.asarray(fn(pool.mu, pool.sigma, pool.acc, pool.rank,
                         jnp.asarray(_pad_batch(t_u, bpad)),
                         jnp.asarray(_pad_batch(t_l, bpad)),
@@ -303,13 +207,10 @@ def select_fused(pool: DevicePool, t_u, t_l, *, gamma: float = 1.0,
 # ======================================================================
 # Fleet selection: the fused pipeline over a leading cell axis.  Every
 # cell's pending batch is judged in ONE device call — (cell × batch ×
-# pool) operands in, (cell × batch) picks out.  The per-cell math is
-# exactly the `_fused_select` jnp path (stages 1–2, Eq. 3–4 utilities,
-# inverse-CDF draw); cells ride `jax.vmap`, and
-# `distributed.shardmap_ops.sharded_fleet_select` wraps the same body
-# under `shard_map` when a mesh carries a "cell" axis.  The jnp branch
-# is used on every backend (no Pallas inside the vmapped body), so the
-# call is bit-identical between CPU tests and sharded meshes.
+# pool) operands in, (cell × batch) picks out.  Cells ride `jax.vmap`,
+# and `distributed.shardmap_ops.sharded_fleet_select` wraps the same
+# body under `shard_map` when a mesh carries a "cell" axis, so the call
+# is bit-identical between CPU tests and sharded meshes.
 # ======================================================================
 
 def fleet_select_body(mu, sig, acc, rank, t_u, t_l, key, *,
@@ -320,15 +221,11 @@ def fleet_select_body(mu, sig, acc, rank, t_u, t_l, key, *,
     t_u/t_l: (B,) budget bounds; key: a PRNG key.  Returns (B,) int32
     picks, −1 where no base model exists (the caller's shed/fallback
     lane)."""
+    mu, sig = mu[None, :], sig[None, :]
     base, has_base, eligible = _stages12(mu, sig, rank, t_u, t_l)
     w = _utilities(mu, sig, acc, t_u, t_l, eligible, gamma)
-    cdf = jnp.cumsum(w, axis=1)
-    total = cdf[:, -1]
-    r01 = jax.random.uniform(key, total.shape, dtype=cdf.dtype)
-    thresh = r01 * total
-    choice = jnp.argmax(cdf > thresh[:, None], axis=1).astype(jnp.int32)
-    choice = jnp.where(total > thresh, choice, base)
-    return jnp.where(has_base, choice, -1)
+    r01 = jax.random.uniform(key, t_u.shape, dtype=w.dtype)
+    return _draw(w, base, has_base, r01, -1)
 
 
 @functools.lru_cache(maxsize=16)
@@ -364,73 +261,30 @@ def select_fleet_stacked(mu, sig, acc, rank, t_u, t_l, *,
 
 # ======================================================================
 # Class-conditional selection: the fused pipeline with PER-REQUEST pool
-# operands.  The premodel layer keeps K per-class profile tables over
-# the same zoo (premodel.conditional.ConditionalProfileStore); a batch
+# rows.  The premodel layer keeps K per-class profile tables over the
+# same zoo (premodel.conditional.ConditionalProfileStore); a batch
 # carrying per-request input-class ids gathers each request's class row
-# out of the stacked (K, npad) mu/sigma operands and runs the identical
-# stage 1–3 math row-wise — ONE device call for the whole classed
-# batch, exactly like the fleet's stacked dispatch.  Accuracy (and the
-# stage-1 rank derived from it) never varies by class, so acc/rank stay
-# (npad,) and broadcast.  jnp on every backend (no Pallas inside), so
-# CPU tests and TPU runs are bit-identical.
+# out of the stacked (K, npad) mu/sigma operands and runs the same
+# stage 1–3 body — ONE device call for the whole classed batch.
+# Accuracy (and the stage-1 rank derived from it) never varies by
+# class, so acc/rank stay (npad,) and broadcast.
 # ======================================================================
-
-def _stages12_rows(mu, sig, rank, t_u, t_l):
-    """Stages 1–2 with per-request pool rows.  mu/sig: (B, npad);
-    rank: (npad,); t_u/t_l: (B,).  Same math as :func:`_stages12`, with
-    the base row's μ/σ gathered per request instead of indexed from a
-    shared pool vector."""
-    tu, tl = t_u[:, None], t_l[:, None]
-    mus = mu + sig
-    elig1 = (mus < tu) & ((mu - sig) < tl)                   # Eq. 2, (B, npad)
-    has_base = elig1.any(axis=1)
-    base = jnp.argmin(jnp.where(elig1, rank[None, :], PAD_RANK + 1.0),
-                      axis=1).astype(jnp.int32)              # first in acc order
-    mu_base = jnp.take_along_axis(mu, base[:, None], axis=1)[:, 0]
-    sig_base = jnp.take_along_axis(sig, base[:, None], axis=1)[:, 0]
-    half = jnp.abs(t_l - mu_base) + sig_base                 # (B,)
-    lo, hi = (t_l - half)[:, None], (t_l + half)[:, None]
-    natural = (lo <= mu) & (mu <= hi) & (mus < tu)
-    eligible = natural | (jnp.arange(mu.shape[1])[None, :] == base[:, None])
-    eligible &= has_base[:, None]
-    return base, has_base, eligible
-
-
-def _utilities_rows(mu, sig, acc, t_u, t_l, eligible, gamma):
-    """Eq. 3–4 utilities with per-request μ/σ rows (same degenerate
-    fallback as :func:`_utilities`)."""
-    tu, tl = t_u[:, None], t_l[:, None]
-    num = tu - (mu + sig)
-    den = jnp.maximum(jnp.abs(tl - mu), EPS)
-    u = jnp.power(jnp.maximum(acc, EPS), gamma)[None, :] * num / den
-    u = jnp.where(eligible, u, 0.0)
-    total = jnp.sum(u, axis=1, keepdims=True)
-    good = jnp.isfinite(total) & (total > 0)
-    return jnp.where(good, u, eligible.astype(u.dtype))
-
 
 def _classed_select(mu_k, sig_k, acc, rank, cls, shifts, t_u, t_l, seed, *,
                     gamma: float):
     """The classed pipeline under one trace: gather each request's class
     row, add the (class-independent) queue-wait shifts, then stages 1–3
-    and the inverse-CDF draw.  Returns (B,) int32 picks with the
-    no-base fallback resolved to the row's own fastest model, plus the
-    has_base mask."""
+    and the draw.  Returns (B,) int32 picks with the no-base fallback
+    resolved to the row's own fastest model (padded lanes carry PAD_MU
+    and never win the argmin), plus the has_base mask."""
     mu = mu_k[cls] + shifts[None, :]       # (B, npad); shifts are per-model
     sig = sig_k[cls]
-    base, has_base, eligible = _stages12_rows(mu, sig, rank, t_u, t_l)
-    w = _utilities_rows(mu, sig, acc, t_u, t_l, eligible, gamma)
-    cdf = jnp.cumsum(w, axis=1)
-    total = cdf[:, -1]
-    r01 = jax.random.uniform(jax.random.PRNGKey(seed), total.shape,
-                             dtype=cdf.dtype)
-    thresh = r01 * total
-    choice = jnp.argmax(cdf > thresh[:, None], axis=1).astype(jnp.int32)
-    choice = jnp.where(total > thresh, choice, base)
-    # Fallback: the fastest model of the request's OWN class view
-    # (padded lanes carry PAD_MU and never win the argmin).
-    fb = jnp.argmin(mu, axis=1).astype(jnp.int32)
-    return jnp.where(has_base, choice, fb), has_base
+    base, has_base, eligible = _stages12(mu, sig, rank, t_u, t_l)
+    w = _utilities(mu, sig, acc, t_u, t_l, eligible, gamma)
+    r01 = jax.random.uniform(jax.random.PRNGKey(seed), t_u.shape,
+                             dtype=w.dtype)
+    fastest = jnp.argmin(mu, axis=1).astype(jnp.int32)
+    return _draw(w, base, has_base, r01, fastest), has_base
 
 
 @functools.lru_cache(maxsize=32)
@@ -502,16 +356,11 @@ def _charged_step(rep_wait, xs, *, mu, sig, acc, rank, mu_charge,
         cost = cost + mu_charge
     admitted = jnp.any(cost < lim)
 
-    mu_i = mu + wq                       # the shifted-μ store view
-    base, has_base, eligible = _stages12(mu_i, sig, rank,
-                                         tu[None], tl[None])
-    w = _utilities(mu_i, sig, acc, tu[None], tl[None], eligible, gamma)
-    cdf = jnp.cumsum(w[0])
-    total = cdf[-1]
-    thresh = r01 * total
-    choice = jnp.argmax(cdf > thresh).astype(jnp.int32)
-    choice = jnp.where(total > thresh, choice, base[0])
-    pick = jnp.where(has_base[0], choice, fastest)
+    # The shifted-μ store view, as one shared pool row.
+    mu_i, sig, tu, tl = (mu + wq)[None, :], sig[None, :], tu[None], tl[None]
+    base, has_base, eligible = _stages12(mu_i, sig, rank, tu, tl)
+    w = _utilities(mu_i, sig, acc, tu, tl, eligible, gamma)
+    pick = _draw(w, base, has_base, r01[None], fastest)[0]
 
     # Charge: least-loaded capable replica, first-index tie-break (the
     # pool-order rule ``ReplicaPool.best_for`` uses).
@@ -826,7 +675,7 @@ def masks_device(pool: DevicePool, t_u, t_l):
     B = len(t_u)
     bpad = _bucket(B, 8)
     base, has, elig = _masks_jit(pool.npad)(
-        pool.mu, pool.sigma, pool.rank,
+        pool.mu[None, :], pool.sigma[None, :], pool.rank,
         jnp.asarray(_pad_batch(t_u, bpad)),
         jnp.asarray(_pad_batch(t_l, bpad)))
     return (np.asarray(base)[:B], np.asarray(has)[:B],

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (small-shape ground truth)."""
+"""Pure-jnp oracles for the kernels and the device selection stages
+(small-shape ground truth)."""
 from __future__ import annotations
 
 import jax
@@ -66,23 +67,6 @@ def ssd_scan_ref(x, dt, A, B_, C_):
     _, ys = jax.lax.scan(step, h0, (jnp.moveaxis(decay, 2, 0), upd,
                                     jnp.moveaxis(Cx, 2, 0)))
     return jnp.moveaxis(ys, 0, 2).astype(x.dtype)  # (B,H,S,hd)
-
-
-def policy_probs_ref(mu, sigma, acc, t_u, t_l, elig, *, gamma=1.0,
-                     eps=1e-9):
-    """Batched ModiPick stage-3 (Eqs. 3–4) oracle.  mu/sigma/acc: (n,);
-    t_u/t_l: (B,); elig: (B, n) mask → (B, n) probability rows (all-zero
-    where a row has no eligible model)."""
-    muf = mu.astype(jnp.float32)
-    num = t_u.astype(jnp.float32)[:, None] - (muf + sigma.astype(jnp.float32))
-    den = jnp.maximum(jnp.abs(t_l.astype(jnp.float32)[:, None] - muf), eps)
-    u = jnp.maximum(acc.astype(jnp.float32), eps)[None, :] ** gamma * num / den
-    u = jnp.where(elig > 0, u, 0.0)
-    total = u.sum(axis=1, keepdims=True)
-    cnt = (elig > 0).sum(axis=1, keepdims=True)
-    good = jnp.isfinite(total) & (total > 0)
-    uniform = (elig > 0) / jnp.maximum(cnt, 1)
-    return jnp.where(good, u / jnp.where(good, total, 1.0), uniform)
 
 
 def modipick_masks_ref(mu, sigma, rank, t_u, t_l, *, pad_rank=1e9):

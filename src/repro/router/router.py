@@ -36,21 +36,25 @@ B sequential singleton ``route`` calls (the trusted scalar path) would
 produce.  ``charge=False`` keeps the historical one-snapshot semantics
 (the speculative-lookahead contract, and the ablation baseline).
 
-Per batch, the router:
+Per batch, every path runs the same decision core:
 
-1. resolves the wait telemetry once — a live :class:`ChargedWaits`
-   state, a frozen ``w_queue_map`` snapshot, a ``w_queue_fn`` estimator,
-   or the store's own EWMA queue telemetry;
-2. runs the pluggable :class:`AdmissionController` per request *before*
+1. the budget column ``T_sla − 2·T_input`` once, through
+   ``core.policy.budget``;
+2. the wait telemetry once (``_resolve_waits``) — a live
+   :class:`ChargedWaits` state, a frozen ``w_queue_map`` snapshot, a
+   ``w_queue_fn`` estimator, or the store's own EWMA queue telemetry;
+3. the pluggable :class:`AdmissionController` per request *before*
    selection — shed requests never spend a selection (nor a charge);
-3. selects for the admitted requests: a singleton batch rides the scalar
-   ``policy.select_traced``/``select_lean`` (draw-for-draw identical to
-   the historical per-request call sites, which is what keeps seeded
-   single-SLA goldens bit-identical); a charged batch rides the same
-   scalar core sequentially (or the device-resident charged pass in
-   ``kernels.policy_select`` on the jax backend: one Pallas kernel on
-   the TPU, a ``lax.scan`` elsewhere); an uncharged batch
-   rides the vectorized ``policy_vec.select_batch_traced``.
+4. selection for the admitted requests: a singleton batch rides the
+   scalar ``policy.select_traced``/``select_lean`` (draw-for-draw
+   identical to the historical per-request call sites, which is what
+   keeps seeded single-SLA goldens bit-identical); a charged batch rides
+   the same scalar core sequentially, or on the jax backend the
+   device-resident charged pass in ``kernels.policy_select`` (one
+   Pallas kernel on the TPU, a ``lax.scan`` elsewhere); an uncharged
+   batch rides the vectorized ``policy_vec.select_batch_traced``;
+5. one row write per routed request (``_put``), or a column write for
+   the device passes, and one tally of the counters from the columns.
 
 Queue-aware mode presents the policy with the shifted-μ store view
 (``router.queueaware.shifted_store``), exactly as the per-call-site
@@ -221,53 +225,72 @@ class Router:
             res = BatchDecisions.empty(B, tab.names, traces=want_traces)
             if B == 0:
                 return res
-
-            # -- resolve the wait telemetry once per batch ------------------
-            needs_waits = self.queue_aware or self.admission.needs_w_queue
-            state: Optional[ChargedWaits] = None
-            waits: Optional[Dict[str, float]] = None
-            if needs_waits:
-                if charged is not None:
-                    state = charged
-                elif w_queue_map is not None:
-                    waits = w_queue_map
-                else:
-                    # No injected snapshot: query per model, falling back to
-                    # the store's own EWMA queue telemetry (0 until the
-                    # first observation) absent an estimator.
-                    fn = w_queue_fn or self.store.queue_wait
-                    waits = {n: max(0.0, float(fn(n)))
-                             for n in self.store.profiles}
+            budgets = budget(t_sla, t_input)
+            state, waits = self._resolve_waits(charged, w_queue_map,
+                                               w_queue_fn)
 
             if B == 1:
-                self._route_singleton(
-                    res, float(t_sla[0]), float(t_input[0]), rng, state, waits,
-                    depth_fn,
-                    _requests[0] if _requests is not None else None,
-                    sla_class[0] if sla_class is not None else None)
-            elif charge and needs_waits:
+                if state is not None:
+                    waits = state.as_map()
+                mid, fb, w_q, reason, trace = self._route_scalar(
+                    float(budgets[0]), float(t_sla[0]), float(t_input[0]),
+                    rng, waits, depth_fn, _requests, sla_class)
+                if mid < 0:
+                    self._shed(res, 0, reason, w_q)
+                else:
+                    self._put(res, 0, mid, fb, w_q, trace)
+            elif charge and (state is not None or waits is not None):
                 if state is None:
                     # Snapshot-only telemetry: charge at model granularity
                     # (each model its own queue — the per-model-endpoint
                     # topology) so the fix does not require a replica pool.
                     state = ChargedWaits.per_model(
                         tab.names, [waits[n] for n in tab.names], tab.mu)
-                self._route_charged(res, t_sla, t_input, rng, state, depth_fn,
-                                    _requests, sla_class)
+                self._route_charged(res, budgets, t_sla, t_input, rng, state,
+                                    depth_fn, _requests, sla_class)
             else:
-                self._route_snapshot(res, t_sla, t_input, rng,
+                self._route_snapshot(res, budgets, t_sla, t_input, rng,
                                      state.as_map() if state is not None
                                      else waits,
                                      depth_fn, _requests, sla_class)
-
-            self.n_batches += 1
-            self.n_routed += B
-            n_admitted = int(res.admitted.sum())
-            self.n_admitted += n_admitted
-            self.n_shed += B - n_admitted
+            self._tally(B, int(res.admitted.sum()), int(res.fallback.sum()))
             return res
 
     # ------------------------------------------------------------------
+    # the decision core every path shares
+    # ------------------------------------------------------------------
+    def _resolve_waits(self, charged=None, w_queue_map=None,
+                       w_queue_fn=None):
+        """One batch's wait telemetry, resolved once: ``(state, waits)``
+        — a live :class:`ChargedWaits` state, or a name → clamped wait
+        snapshot (``w_queue_map`` as given, else ``w_queue_fn`` or the
+        store's own EWMA queue telemetry queried per model, 0 until the
+        first observation).  Both None when neither queue-aware
+        selection nor admission reads waits."""
+        if not (self.queue_aware or self.admission.needs_w_queue):
+            return None, None
+        if charged is not None:
+            return charged, None
+        if w_queue_map is not None:
+            return None, w_queue_map
+        fn = w_queue_fn or self.store.queue_wait
+        return None, {n: max(0.0, float(fn(n))) for n in self.store.profiles}
+
+    def _selection_store(self, store: ProfileStore, waits):
+        """The store the policy selects against: in queue-aware mode the
+        shifted-μ view over ``waits``, which is already the clamped
+        snapshot, so the view reuses it instead of re-querying."""
+        if self.queue_aware and waits is not None:
+            return shifted_store(store, waits.__getitem__, shifts=waits)
+        return store
+
+    @property
+    def _select(self):
+        """The scalar selection core: the full trace, or without trace
+        detail the lean core (same stages, same RNG stream)."""
+        return (self.policy.select_traced if self.trace_detail
+                else self.policy.select_lean)
+
     def _admission_request(self, requests, sla_class, i,
                            t_sla: float, t_input: float) -> InferenceRequest:
         if requests is not None:
@@ -275,6 +298,28 @@ class Router:
         return InferenceRequest(
             t_sla_ms=t_sla, t_input_ms=t_input, rid=i,
             sla_class=sla_class[i] if sla_class is not None else None)
+
+    def _admit(self, res, budgets, t_sla, t_input, tab, waits, depth_fn,
+               requests, sla_class) -> List[int]:
+        """One admission pass over a batch judged against one snapshot:
+        the admitted rows in order; shed rows are written into ``res``.
+        A controller that never overrode the base no-op verdict is not
+        called at all."""
+        if self._admits_all:
+            return list(range(len(budgets)))
+        w_fn = waits.__getitem__ if waits is not None else None
+        w_min = min(waits.values()) if waits else 0.0
+        admitted = []
+        for i in range(len(budgets)):
+            req = self._admission_request(requests, sla_class, i,
+                                          float(t_sla[i]), float(t_input[i]))
+            ok, reason = self.admission.admit(req, float(budgets[i]), tab,
+                                              w_fn, depth_fn)
+            if ok:
+                admitted.append(i)
+            else:
+                self._shed(res, i, reason, w_min)
+        return admitted
 
     @staticmethod
     def _reason_code(res: BatchDecisions, reason: str) -> int:
@@ -290,31 +335,44 @@ class Router:
         res.reject_code[i] = self._reason_code(res, reason)
         res.w_queue_ms[i] = w_min
 
-    def _route_scalar(self, t_sla, t_input, rng, waits, depth_fn,
-                      request, cls):
+    @staticmethod
+    def _put(res: BatchDecisions, i: int, mid: int, fallback: bool,
+             w: float, trace) -> None:
+        """Write one routed row."""
+        res.model_idx[i] = mid
+        res.admitted[i] = True
+        res.fallback[i] = fallback
+        res.w_queue_ms[i] = w
+        if res.traces is not None:
+            res.traces[i] = trace
+
+    def _tally(self, n: int, n_admitted: int, n_fallback: int) -> None:
+        """Count one routed batch (a shed row never sets ``fallback``,
+        so a batch's fallback column counts admitted rows only)."""
+        self.n_batches += 1
+        self.n_routed += n
+        self.n_admitted += n_admitted
+        self.n_shed += n - n_admitted
+        self.n_fallback += n_fallback
+
+    def _route_scalar(self, b0, t_sla, t_input, rng, waits, depth_fn,
+                      requests, sla_class):
         """The scalar core — draw-for-draw identical to the historical
-        per-request call sites (python-float budget math, one shifted
+        per-request call sites (python-float budget ``b0``, one shifted
         view, ``select_traced``/``select_lean``).  Returns
         ``(mid, fallback, w_queue_ms, reason, trace)`` with ``mid == -1``
         (and the shed reason) when admission rejects."""
-        b0 = budget(t_sla, t_input)
         w_fn = waits.__getitem__ if waits is not None else None
         if not self._admits_all:
-            req = (request if request is not None else
-                   self._admission_request(None, (cls,), 0, t_sla, t_input))
+            req = self._admission_request(requests, sla_class, 0, t_sla,
+                                          t_input)
             ok, reason = self.admission.admit(req, b0, self.store.table(),
                                               w_fn, depth_fn)
             if not ok:
                 return (-1, False,
                         min(waits.values()) if waits else 0.0, reason, None)
-        # ``waits`` is already the clamped per-batch snapshot, so the
-        # shifted view reuses it instead of re-querying.
-        sel_store = (shifted_store(self.store, w_fn, shifts=waits)
-                     if (self.queue_aware and w_fn is not None)
-                     else self.store)
-        select = (self.policy.select_traced if self.trace_detail
-                  else self.policy.select_lean)
-        trace = select(sel_store, b0, rng)
+        trace = self._select(self._selection_store(self.store, waits), b0,
+                             rng)
         self.store.mark_selected(trace.chosen)
         mid = self.store.table().index[trace.chosen]
         return (mid, trace.fallback,
@@ -334,120 +392,51 @@ class Router:
         :class:`BatchDecisions` the caller of a singleton batch rarely
         wants — the engine's continuous-arrival runs are ~all singleton
         batches)."""
-        waits = None
-        if self.queue_aware or self.admission.needs_w_queue:
-            if w_queue_map is not None:
-                waits = w_queue_map
-            else:
-                fn = w_queue_fn or self.store.queue_wait
-                waits = {n: max(0.0, float(fn(n)))
-                         for n in self.store.profiles}
+        _, waits = self._resolve_waits(None, w_queue_map, w_queue_fn)
+        t_sla, t_input = float(t_sla_ms), float(t_input_ms)
         mid, fb, w_q, reason, _ = self._route_scalar(
-            float(t_sla_ms), float(t_input_ms), rng, waits, depth_fn,
-            None, sla_class)
-        self.n_batches += 1
-        self.n_routed += 1
-        if mid < 0:
-            self.n_shed += 1
-        else:
-            self.n_admitted += 1
-            if fb:
-                self.n_fallback += 1
+            budget(t_sla, t_input), t_sla, t_input, rng, waits, depth_fn,
+            None, (sla_class,))
+        self._tally(1, int(mid >= 0), int(mid >= 0 and fb))
         return mid, fb, w_q, reason
 
-    def _route_singleton(self, res, t_sla, t_input, rng, state, waits,
-                         depth_fn, request, cls) -> None:
-        """Batch-of-one adapter over :meth:`_route_scalar` writing into
-        a :class:`BatchDecisions` column set."""
-        if state is not None:
-            waits = state.as_map()
-        mid, fb, w_q, reason, trace = self._route_scalar(
-            t_sla, t_input, rng, waits, depth_fn, request, cls)
-        if mid < 0:
-            self._shed(res, 0, reason, w_q)
-            return
-        res.model_idx[0] = mid
-        res.admitted[0] = True
-        res.fallback[0] = fb
-        res.w_queue_ms[0] = w_q
-        if fb:
-            self.n_fallback += 1
-        if res.traces is not None:
-            res.traces[0] = trace
-
-    def _route_snapshot(self, res, t_sla, t_input, rng, waits, depth_fn,
-                        requests, sla_class) -> None:
+    def _route_snapshot(self, res, budgets, t_sla, t_input, rng, waits,
+                        depth_fn, requests, sla_class) -> None:
         """The historical one-snapshot batch: every request judged and
         selected against the same waits (speculative-lookahead
         contract; the ``snapshot`` ablation arm)."""
-        B = len(t_sla)
-        budgets = t_sla - 2.0 * t_input
         tab = self.store.table()
-        w_fn = waits.__getitem__ if waits is not None else None
-        if self._admits_all:
-            # The base no-op verdict: skip the per-request call.
-            admitted = list(range(B))
-        else:
-            admitted = []
-            w_min = min(waits.values()) if waits else 0.0
-            for i in range(B):
-                req = self._admission_request(requests, sla_class, i,
-                                              float(t_sla[i]),
-                                              float(t_input[i]))
-                ok, reason = self.admission.admit(req, float(budgets[i]),
-                                                  tab, w_fn, depth_fn)
-                if ok:
-                    admitted.append(i)
-                else:
-                    self._shed(res, i, reason, w_min)
+        admitted = self._admit(res, budgets, t_sla, t_input, tab, waits,
+                               depth_fn, requests, sla_class)
         if not admitted:
             return
-        # ``waits`` is already the clamped per-batch snapshot, so the
-        # shifted view reuses it instead of re-querying.
-        sel_store = (shifted_store(self.store, w_fn, shifts=waits)
-                     if (self.queue_aware and w_fn is not None)
-                     else self.store)
-        if len(admitted) == 1:
-            # Scalar path: draw-for-draw identical to a historical
-            # per-request ``select_traced`` call site.  Without trace
-            # detail the lean core skips the eligible/probs tuple
-            # materialisation — same stages, same RNG stream.
-            i = admitted[0]
-            select = (self.policy.select_traced if self.trace_detail
-                      else self.policy.select_lean)
-            traces = [select(sel_store, float(budgets[i]), rng)]
-        else:
-            traces = policy_vec.select_batch_traced(
-                self.policy, sel_store, budgets[admitted], rng,
-                backend=self.backend, detail=self.trace_detail)
+        # A single admitted row rides the scalar core inside
+        # ``select_batch_traced``: draw-for-draw a per-request call site.
+        traces = policy_vec.select_batch_traced(
+            self.policy, self._selection_store(self.store, waits),
+            budgets[admitted], rng, backend=self.backend,
+            detail=self.trace_detail)
         for i, trace in zip(admitted, traces):
             self.store.mark_selected(trace.chosen)
-            res.model_idx[i] = tab.index[trace.chosen]
-            res.admitted[i] = True
-            res.fallback[i] = trace.fallback
-            res.w_queue_ms[i] = waits[trace.chosen] if waits else 0.0
-            if trace.fallback:
-                self.n_fallback += 1
-            if res.traces is not None:
-                res.traces[i] = trace
+            self._put(res, i, tab.index[trace.chosen], trace.fallback,
+                      waits[trace.chosen] if waits else 0.0, trace)
 
-    def _route_charged(self, res, t_sla, t_input, rng, state: ChargedWaits,
-                       depth_fn, requests, sla_class) -> None:
+    def _route_charged(self, res, budgets, t_sla, t_input, rng,
+                       state: ChargedWaits, depth_fn, requests,
+                       sla_class) -> None:
         """Sequential-greedy charged routing: request ``i`` is admitted
         and selected against waits that already include the charges of
         requests ``0..i-1`` — pick-for-pick what B sequential singleton
         ``route`` calls with live wait updates would produce."""
-        B = len(t_sla)
-        budgets = t_sla - 2.0 * t_input
-        tab = self.store.table()
+        B = len(budgets)
         if self._use_charged_scan(B):
             self._route_charged_jax(res, budgets, rng, state)
             return
         with span("router.charged_loop"):
+            tab = self.store.table()
             names = tab.names
             index = tab.index
-            select = (self.policy.select_traced if self.trace_detail
-                      else self.policy.select_lean)
+            select = self._select
             check_admission = not self._admits_all
             for i in range(B):
                 wq = state.model_waits()
@@ -465,20 +454,11 @@ class Router:
                     if not ok:
                         self._shed(res, i, reason, float(wq.min()))
                         continue
-                sel_store = (shifted_store(self.store, waits.__getitem__,
-                                           shifts=waits)
-                             if self.queue_aware else self.store)
-                trace = select(sel_store, float(budgets[i]), rng)
+                trace = select(self._selection_store(self.store, waits),
+                               float(budgets[i]), rng)
                 self.store.mark_selected(trace.chosen)
                 mid = index[trace.chosen]
-                res.model_idx[i] = mid
-                res.admitted[i] = True
-                res.fallback[i] = trace.fallback
-                res.w_queue_ms[i] = float(wq[mid])
-                if trace.fallback:
-                    self.n_fallback += 1
-                if res.traces is not None:
-                    res.traces[i] = trace
+                self._put(res, i, mid, trace.fallback, float(wq[mid]), trace)
                 # Charge the pick before the next request is judged; the
                 # returned replica is where a placement-consistent caller
                 # should enqueue it.
@@ -512,37 +492,14 @@ class Router:
         t_input = np.asarray(t_input_ms, dtype=np.float64)
         cls = np.asarray(cls, dtype=np.int32)
         B = len(t_sla)
-        store = self.store
-        pooled = store.pooled_table()
+        pooled = self.store.pooled_table()
         res = BatchDecisions.empty(B, pooled.names)
         if B == 0:
             return res
-
-        waits: Optional[Dict[str, float]] = None
-        if self.queue_aware or self.admission.needs_w_queue:
-            if w_queue_map is not None:
-                waits = w_queue_map
-            else:
-                waits = {n: max(0.0, float(store.queue_wait(n)))
-                         for n in store.profiles}
-        w_fn = waits.__getitem__ if waits is not None else None
-
-        budgets = t_sla - 2.0 * t_input
-        if self._admits_all:
-            admitted = list(range(B))
-        else:
-            admitted = []
-            w_min = min(waits.values()) if waits else 0.0
-            for i in range(B):
-                req = self._admission_request(None, None, i,
-                                              float(t_sla[i]),
-                                              float(t_input[i]))
-                ok, reason = self.admission.admit(req, float(budgets[i]),
-                                                  pooled, w_fn, depth_fn)
-                if ok:
-                    admitted.append(i)
-                else:
-                    self._shed(res, i, reason, w_min)
+        _, waits = self._resolve_waits(None, w_queue_map)
+        budgets = budget(t_sla, t_input)
+        admitted = self._admit(res, budgets, t_sla, t_input, pooled, waits,
+                               depth_fn, None, None)
         if admitted:
             if type(self.policy) is ModiPick:
                 self._route_classed_jax(res, admitted, budgets, cls, rng,
@@ -550,35 +507,27 @@ class Router:
             else:
                 self._route_classed_scalar(res, admitted, budgets, cls,
                                            rng, waits)
-        self.n_batches += 1
-        self.n_routed += B
-        n_admitted = int(res.admitted.sum())
-        self.n_admitted += n_admitted
-        self.n_shed += B - n_admitted
+        self._tally(B, int(res.admitted.sum()), int(res.fallback.sum()))
         return res
 
     def _route_classed_jax(self, res, admitted, budgets, cls, rng, waits,
                            pooled) -> None:
         from repro.kernels import policy_select
-        store = self.store
-        names = pooled.names
-        shifts = ([waits[n] for n in names]
-                  if (self.queue_aware and waits is not None) else None)
+        w_col = (np.asarray([waits[n] for n in pooled.names], np.float64)
+                 if waits else None)
         idx = np.asarray(admitted, dtype=np.int64)
         picks, has_base = policy_select.select_classed(
-            store.stacked_pool(), cls[idx], budgets[idx],
-            budgets[idx] - self.policy.t_threshold, shifts=shifts,
+            self.store.stacked_pool(), cls[idx], budgets[idx],
+            budgets[idx] - self.policy.t_threshold,
+            shifts=w_col if self.queue_aware else None,
             gamma=self.policy.gamma,
             seed=int(rng.integers(np.iinfo(np.int64).max)))
-        for j, i in enumerate(admitted):
-            mid = int(picks[j])
-            store.mark_selected(names[mid])
-            res.model_idx[i] = mid
-            res.admitted[i] = True
-            res.fallback[i] = not has_base[j]
-            res.w_queue_ms[i] = waits[names[mid]] if waits else 0.0
-            if not has_base[j]:
-                self.n_fallback += 1
+        self.store.mark_selected_many(picks)
+        res.model_idx[idx] = picks
+        res.admitted[idx] = True
+        res.fallback[idx] = ~has_base
+        if w_col is not None:
+            res.w_queue_ms[idx] = w_col[picks]
 
     def _route_classed_scalar(self, res, admitted, budgets, cls, rng,
                               waits) -> None:
@@ -586,26 +535,17 @@ class Router:
         class cursor flips the store's presented table around the
         historical scalar core."""
         store = self.store
-        w_fn = waits.__getitem__ if waits is not None else None
-        select = (self.policy.select_traced if self.trace_detail
-                  else self.policy.select_lean)
         for i in admitted:
             store.set_class(int(cls[i]))
             try:
-                sel_store = (shifted_store(store, w_fn, shifts=waits)
-                             if (self.queue_aware and w_fn is not None)
-                             else store)
-                trace = select(sel_store, float(budgets[i]), rng)
+                trace = self._select(self._selection_store(store, waits),
+                                     float(budgets[i]), rng)
                 mid = store.table().index[trace.chosen]
             finally:
                 store.set_class(-1)
             store.mark_selected(trace.chosen)
-            res.model_idx[i] = mid
-            res.admitted[i] = True
-            res.fallback[i] = trace.fallback
-            res.w_queue_ms[i] = waits[trace.chosen] if waits else 0.0
-            if trace.fallback:
-                self.n_fallback += 1
+            self._put(res, i, mid, trace.fallback,
+                      waits[trace.chosen] if waits else 0.0, None)
 
     # -- device path ---------------------------------------------------
     def _use_charged_scan(self, B: int) -> bool:
@@ -659,7 +599,6 @@ class Router:
             if not state.pseudo:
                 res.replica_idx[admitted] = replica[admitted]
             self.store.mark_selected_many(picks[admitted])
-        self.n_fallback += int((res.admitted & res.fallback).sum())
 
     # ------------------------------------------------------------------
     # recovery surface (router.retry)

@@ -88,35 +88,6 @@ def test_rglru_scan(dtype, B, S, W, block):
                                np.asarray(expect, np.float32), **tol(dtype))
 
 
-@pytest.mark.parametrize("B,n,gamma", [
-    (8, 11, 1.0),      # Table-2 pool, one batch block
-    (300, 12, 4.0),    # ragged batch (padding) + sharpened accuracy
-    (64, 200, 1.0),    # pool wider than one lane tile
-])
-def test_policy_select_probs(B, n, gamma):
-    """Fused ModiPick stage-3 kernel vs the pure-jnp oracle, including
-    all-ineligible (fallback) rows."""
-    rng = np.random.default_rng(42)
-    mu = jnp.asarray(rng.uniform(1.0, 200.0, n), jnp.float32)
-    sigma = jnp.asarray(rng.uniform(0.0, 20.0, n), jnp.float32)
-    acc = jnp.asarray(rng.uniform(0.05, 1.0, n), jnp.float32)
-    t_u = jnp.asarray(rng.uniform(5.0, 300.0, B), jnp.float32)
-    t_l = t_u - 20.0
-    elig = jnp.asarray(
-        (rng.random((B, n)) < 0.4)
-        & (np.asarray(mu + sigma)[None, :] < np.asarray(t_u)[:, None]))
-    elig = elig.at[0].set(False)  # guaranteed fallback row
-    out = ops.modipick_probs(mu, sigma, acc, t_u, t_l, elig, gamma=gamma)
-    expect = ref.policy_probs_ref(mu, sigma, acc, t_u, t_l, elig,
-                                  gamma=gamma)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=2e-5, atol=2e-5)
-    rows = np.asarray(out).sum(axis=1)
-    active = np.asarray(elig).any(axis=1)
-    np.testing.assert_allclose(rows[active], 1.0, rtol=1e-5)
-    np.testing.assert_allclose(rows[~active], 0.0, atol=1e-7)
-
-
 # ----------------------------------------------------------------------
 # device-resident stages 1–2: fused masks vs the numpy reference
 # ----------------------------------------------------------------------
@@ -208,13 +179,51 @@ def test_select_fused_device_resident_picks():
 
 
 def test_fused_jit_cache_reused_across_calls():
-    """The compiled selection is cached per (pool_size, gamma,
-    batch_block): repeated calls must hit the same callable, and
-    distinct gammas must not collide."""
+    """The compiled selection is cached per (pool_size, gamma):
+    repeated calls must hit the same callable, and distinct gammas must
+    not collide."""
     from repro.kernels import policy_select
-    a = policy_select._fused_jit(128, 1.0, 256, False)
-    assert policy_select._fused_jit(128, 1.0, 256, False) is a
-    assert policy_select._fused_jit(128, 4.0, 256, False) is not a
+    a = policy_select._fused_jit(128, 1.0)
+    assert policy_select._fused_jit(128, 1.0) is a
+    assert policy_select._fused_jit(128, 4.0) is not a
+
+
+@pytest.mark.parametrize("n,gamma", [(11, 1.0), (5, 4.0)])
+@pytest.mark.parametrize("seed", [7, 8])
+def test_device_paths_share_one_selection_body(seed, n, gamma):
+    """The other callers of the one stage 1–3 body and draw agree pick
+    for pick with ``select_fused`` on the same seed and budgets:
+    ``select_classed`` over K identical class views (any class ids), and
+    ``charged_select``'s ``lax.scan`` with a zero charge and admit-all
+    (the ledger never moves, so every row sees the uncharged pool)."""
+    from repro.core.zoo import TABLE2, make_store
+    from repro.premodel.conditional import StackedClassPools
+    from repro.router.charging import ChargedWaits
+    from repro.kernels import policy_select
+
+    tab = make_store(TABLE2[:n]).table()
+    pool = tab.device_pool()
+    rng = np.random.default_rng(seed)
+    B = 300
+    t_u = rng.uniform(0.0, 400.0, B)
+    t_l = t_u - 20.0
+    idx, has = policy_select.select_fused(pool, t_u, t_l, gamma=gamma,
+                                          seed=seed)
+    assert has.any() and (~has).any()
+
+    cls = rng.integers(0, 3, B)
+    c_idx, c_has = policy_select.select_classed(
+        StackedClassPools([tab] * 3), cls, t_u, t_l, gamma=gamma, seed=seed)
+    np.testing.assert_array_equal(c_has, has)
+    np.testing.assert_array_equal(c_idx, idx)
+
+    state = ChargedWaits(np.zeros(n), [[m] for m in range(n)], np.ones(n),
+                         np.zeros(n), tab.names)
+    picks, admitted, s_has, _, _ = policy_select.charged_select(
+        pool, t_u, t_l, state, gamma=gamma, seed=seed)
+    assert admitted.all()
+    np.testing.assert_array_equal(s_has, has)
+    np.testing.assert_array_equal(picks, idx)
 
 
 def test_flash_vs_model_xla_path():

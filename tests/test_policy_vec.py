@@ -175,21 +175,31 @@ def test_modipick_batch_frequencies_match_probs():
         assert abs(names.count(name) / B - p) < 0.01
 
 
-def test_backend_env_override_and_validation(monkeypatch):
+def test_backend_env_override_and_validation():
+    """The backend is the caller's argument, or ``auto`` by batch size;
+    an unknown value raises."""
     store = make_store(TABLE2)
     budgets = np.full(8, 200.0)
-    monkeypatch.setenv("REPRO_POLICY_BACKEND", "numpy")
-    assert len(ModiPick(20.0).select_batch(
-        store, budgets, np.random.default_rng(0))) == 8
-    monkeypatch.setenv("REPRO_POLICY_BACKEND", "bogus")
+    for backend in (None, "auto", "numpy", "jax"):
+        assert len(ModiPick(20.0).select_batch(
+            store, budgets, np.random.default_rng(0),
+            backend=backend)) == 8
+    n = policy_vec.JAX_MIN_BATCH
+    assert policy_vec.resolve_backend(None, n - 1) == "numpy"
+    assert policy_vec.resolve_backend("auto", n) == "jax"
+    assert policy_vec.resolve_backend("numpy", n) == "numpy"
+    assert policy_vec.resolve_backend("jax", 2) == "jax"
     with pytest.raises(ValueError):
-        ModiPick(20.0).select_batch(store, budgets, np.random.default_rng(0))
+        ModiPick(20.0).select_batch(store, budgets, np.random.default_rng(0),
+                                    backend="bogus")
 
 
 def test_jax_backend_matches_numpy_distribution():
-    """The jitted/Pallas stage 3 produces the same probability rows as
-    the numpy reference (float32 tolerance) and valid picks."""
-    from repro.kernels import ops
+    """The device body's stage-3 utility rows, normalised, equal the
+    numpy reference's probability rows (float32 tolerance), and the jax
+    backend's picks are valid."""
+    import jax.numpy as jnp
+    from repro.kernels import policy_select
     store = make_store(TABLE2)
     tab = store.table()
     rng = np.random.default_rng(5)
@@ -197,8 +207,14 @@ def test_jax_backend_matches_numpy_distribution():
     t_u, t_l = budgets, budgets - 20.0
     _, has_base, elig, _ = policy_vec.modipick_masks(tab, t_u, t_l)
     expect = policy_vec.modipick_probs(tab, t_u, t_l, elig, 1.0)
-    got = np.asarray(ops.modipick_probs(tab.mu, tab.sigma, tab.accuracy,
-                                        t_u, t_l, elig, gamma=1.0))
+    pool = tab.device_pool()
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    w = np.asarray(policy_select._utilities(
+        pool.mu[None, :], pool.sigma[None, :], pool.acc, f32(t_u), f32(t_l),
+        jnp.asarray(np.pad(elig, ((0, 0), (0, pool.npad - pool.n)))),
+        1.0))[:, :pool.n]
+    total = w.sum(axis=1, keepdims=True)
+    got = np.where(total > 0, w / np.where(total > 0, total, 1.0), 0.0)
     np.testing.assert_allclose(got, expect, rtol=1e-4, atol=1e-5)
     names = ModiPick(20.0).select_batch(store, budgets,
                                         np.random.default_rng(0),

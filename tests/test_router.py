@@ -13,7 +13,7 @@ from typing import Callable
 
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 import repro.sim.engine as engine_mod
 from repro.core import policy_vec
@@ -418,6 +418,8 @@ def test_route_batch_vectorized_heterogeneous_budgets():
 @given(pool_strategy, st.lists(st.floats(-20.0, 500.0), min_size=1,
                                max_size=24),
        st.floats(0.0, 50.0), st.integers(0, 2**31 - 1))
+# A one-budget batch takes the scalar rule in both functions.
+@example(pool=[(1.0, 1.0, 0.0)] * 3, budgets=[2.0], threshold=0.0, seed=0)
 @settings(max_examples=60, deadline=None)
 def test_select_batch_traced_matches_scalar_stages(pool, budgets, threshold,
                                                    seed):
@@ -470,21 +472,23 @@ def test_trace_arrivals_validation():
 
 
 # ----------------------------------------------------------------------
-# satellite: backend env validation lists the valid values
+# satellite: backend validation lists the valid values
 # ----------------------------------------------------------------------
 
-def test_unknown_env_backend_message_lists_valid_values(monkeypatch):
-    monkeypatch.setenv("REPRO_POLICY_BACKEND", "bogus")
+def test_unknown_env_backend_message_lists_valid_values():
+    """An unknown ``backend`` raises with the value and the valid ones,
+    from ``select_batch`` and from a Router's batch."""
     store = make_store(TABLE2)
     with pytest.raises(ValueError) as e:
         ModiPick(20.0).select_batch(store, np.full(4, 200.0),
-                                    np.random.default_rng(0))
-    assert "REPRO_POLICY_BACKEND" in str(e.value)
-    assert "auto, numpy, jax" in str(e.value)
-    with pytest.raises(ValueError, match="auto, numpy, jax"):
-        ModiPick(20.0).select_batch(store, np.full(4, 200.0),
                                     np.random.default_rng(0),
-                                    backend="tpu")
+                                    backend="bogus")
+    assert "'bogus'" in str(e.value)
+    assert "auto, numpy, jax" in str(e.value)
+    router = Router(store, ModiPick(20.0), backend="tpu")
+    with pytest.raises(ValueError, match="auto, numpy, jax"):
+        router.route_batch_arrays(np.full(4, 300.0), np.full(4, 20.0),
+                                  np.random.default_rng(0))
 
 
 # ----------------------------------------------------------------------

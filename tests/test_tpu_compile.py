@@ -58,13 +58,15 @@ def _on(tree, sharding):
 
 @pytest.mark.parametrize("batch", [4096, 102_400])
 def test_fused_selection_compiles_with_pallas_kernel(one_chip, batch):
+    """The fused stage 1–3 selection (plain XLA on every backend) at a
+    full tick and at the largest bucketed batch."""
     lanes = policy_select.LANES
-    fn = policy_select._fused_jit(lanes, 1.0, 256, True)
+    fn = policy_select._fused_jit(lanes, 1.0)
     pool_arg = _sds((lanes,), jnp.float32, one_chip)
     req = _sds((batch,), jnp.float32, one_chip)
     compiled = fn.lower(pool_arg, pool_arg, pool_arg, pool_arg, req, req,
                         _sds((), jnp.uint32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text()
 
 
 def test_charged_select_compiles(one_chip):
