@@ -571,10 +571,11 @@ def _charged_pallas(mu, sig, acc, rank, mu_charge, cand_mask, speed,
 
 def _charged_operands(npad: int, n: int, bpad: int, t_u, t_l, state,
                       adm_limit):
-    """The charged pass's host operands, as numpy in ``run``'s order
-    after the pool columns: ``(mu_charge, cand_mask, speed, rep_wait,
-    t_u, t_l)`` and, last, the admission limit row (``-inf`` on padded
-    rows, ``+inf`` everywhere real when ``adm_limit`` is ``None``)."""
+    """The charged pass's host operands, as numpy in the order of
+    :func:`_charged_pass` after the pool columns: ``(mu_charge,
+    cand_mask, speed, rep_wait, t_u, t_l)`` and, last, the admission
+    limit row (``-inf`` on padded rows, ``+inf`` everywhere real when
+    ``adm_limit`` is ``None``)."""
     cand_mask = np.zeros((npad, len(state.rep_wait)), dtype=bool)
     lens = [len(c) for c in state.cand]
     cand_mask[np.repeat(np.arange(len(lens)), lens),
@@ -590,28 +591,81 @@ def _charged_operands(npad: int, n: int, bpad: int, t_u, t_l, state,
             _pad_batch(t_u, bpad), _pad_batch(t_l, bpad), lim)
 
 
+def _key_words(seed: int) -> np.ndarray:
+    """The two ``uint32`` words of ``jax.random.PRNGKey(seed)`` for a
+    Python int seed, made on the host: the low 32 bits, and above them
+    the next 32 where x64 is on (``0`` where it is off)."""
+    hi = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, seed & 0xFFFFFFFF], np.uint32)
+
+
+def _charged_layout(npad: int, n_replicas: int, bpad: int):
+    """``(start, stop)`` of each field of the packed tick buffer:
+    :func:`_charged_operands`' seven columns, then the two key words."""
+    sizes = (npad, npad * n_replicas, n_replicas, n_replicas,
+             bpad, bpad, bpad, 2)
+    stops = np.cumsum(sizes).tolist()
+    return tuple(zip([0] + stops[:-1], stops))
+
+
+def _charged_pack(host, seed: int) -> np.ndarray:
+    """One contiguous ``uint32`` buffer of the tick's host operands (in
+    :func:`_charged_layout`'s order; floats by their bits, the mask as
+    0/1) and the draw's key words."""
+    bits = lambda x: np.asarray(x, np.float32).view(np.uint32)
+    mu_charge, cand_mask, *rows = host
+    return np.concatenate([bits(mu_charge),
+                           cand_mask.astype(np.uint32).ravel(),
+                           *map(bits, rows), _key_words(seed)])
+
+
+def _charged_unpack(packed, npad: int, n_replicas: int, bpad: int):
+    """The inverse of :func:`_charged_pack`, traced: the seven operands
+    (the mask as ``bool (npad, R)``), then the key words."""
+    fields = [packed[a:b] for a, b in _charged_layout(npad, n_replicas,
+                                                      bpad)]
+    f32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.float32)
+    mu_charge, cand, speed, rep_wait, t_u, t_l, lim, key = fields
+    return (f32(mu_charge), (cand != 0).reshape(npad, n_replicas),
+            f32(speed), f32(rep_wait), f32(t_u), f32(t_l), f32(lim), key)
+
+
+def _charged_pass(mu, sig, acc, rank, mu_charge, cand_mask, speed,
+                  rep_wait, t_u, t_l, r01, lim, *, gamma: float,
+                  slack: float, include_mu: bool, fastest: int,
+                  use_kernel: bool, interpret: bool):
+    """The charged pass over unpacked operands: the ``lax.scan`` over
+    :func:`_charged_step`, or with ``use_kernel`` the Pallas kernel
+    (``interpret`` runs it through the Pallas interpreter)."""
+    if use_kernel:
+        return _charged_pallas(
+            mu, sig, acc, rank, mu_charge, cand_mask, speed, rep_wait,
+            t_u, t_l, r01, lim, gamma=gamma, slack=slack,
+            include_mu=include_mu, fastest=fastest, interpret=interpret)
+    step = functools.partial(
+        _charged_step, mu=mu, sig=sig, acc=acc, rank=rank,
+        mu_charge=mu_charge, cand_mask=cand_mask, speed=speed,
+        gamma=gamma, slack=slack, include_mu=include_mu, fastest=fastest)
+    _, ys = jax.lax.scan(step, rep_wait, (t_u, t_l, r01, lim))
+    return ys
+
+
 @functools.lru_cache(maxsize=32)
 def _charged_jit(npad: int, gamma: float, slack: float, include_mu: bool,
                  fastest: int, use_kernel: bool = False,
                  interpret: bool = False):
-    """The charged pass's jit cache: the ``lax.scan`` over
-    :func:`_charged_step`, or with ``use_kernel`` the Pallas kernel
-    (``interpret`` runs it through the Pallas interpreter)."""
-    def run(mu, sig, acc, rank, mu_charge, cand_mask, speed, rep_wait,
-            t_u, t_l, r01, lim):
-        if use_kernel:
-            return _charged_pallas(
-                mu, sig, acc, rank, mu_charge, cand_mask, speed, rep_wait,
-                t_u, t_l, r01, lim, gamma=gamma, slack=slack,
-                include_mu=include_mu, fastest=fastest, interpret=interpret)
-        step = functools.partial(
-            _charged_step, mu=mu, sig=sig, acc=acc, rank=rank,
-            mu_charge=mu_charge, cand_mask=cand_mask, speed=speed,
-            gamma=gamma, slack=slack, include_mu=include_mu,
-            fastest=fastest)
-        _, ys = jax.lax.scan(step, rep_wait, (t_u, t_l, r01, lim))
-        return ys
-    return jax.jit(run)
+    """The charged pass's jit cache: one program ``run`` that unpacks
+    the tick's buffer, draws its uniforms and runs
+    :func:`_charged_pass`.  The buffer's layout is static per
+    ``(n_replicas, bpad)``."""
+    def run(mu, sig, acc, rank, packed, *, n_replicas: int, bpad: int):
+        *cols, lim, key = _charged_unpack(packed, npad, n_replicas, bpad)
+        r01 = jax.random.uniform(key, (bpad,), dtype=jnp.float32)
+        return _charged_pass(
+            mu, sig, acc, rank, *cols, r01, lim, gamma=gamma, slack=slack,
+            include_mu=include_mu, fastest=fastest, use_kernel=use_kernel,
+            interpret=interpret)
+    return jax.jit(run, static_argnames=("n_replicas", "bpad"))
 
 
 def charged_select(pool: DevicePool, t_u, t_l, state, *,
@@ -636,23 +690,21 @@ def charged_select(pool: DevicePool, t_u, t_l, state, *,
     indicator, the replica the charge landed on, and the chosen model's
     pre-charge wait (for shed rows: the pool's minimum wait).
 
-    Like the uncharged fused path, the draw is categorical from the
-    exact per-request distribution but rides jax's RNG — same law as
-    the numpy sequential loop, not the same stream.
+    A tick is one host→device transfer (the operands and the key words
+    of ``PRNGKey(seed)``, packed into one buffer) and one program, which
+    draws the batch's uniforms itself.  Like the uncharged fused path,
+    the draw is categorical from the exact per-request distribution but
+    rides jax's RNG — same law as the numpy sequential loop, not the
+    same stream.
     """
     B = len(t_u)
     n, npad = pool.n, pool.npad
     R = len(state.rep_wait)
     bpad = _bucket(B, block_b)
-    f32 = jnp.float32
 
     with span("router.select.pack"):
         host = _charged_operands(npad, n, bpad, t_u, t_l, state, adm_limit)
-        *dev, lim_dev = jax.device_put(host)
-        args = (pool.mu, pool.sigma, pool.acc, pool.rank, *dev)
-    with span("router.select.draw"):
-        r01 = jax.random.uniform(jax.random.PRNGKey(seed), (bpad,),
-                                 dtype=f32)
+        packed = jax.device_put(_charged_pack(host, seed))
 
     kernel = charged_kernel_engaged(npad, R)
     fn = _charged_jit(npad, float(gamma), float(adm_slack),
@@ -661,7 +713,8 @@ def charged_select(pool: DevicePool, t_u, t_l, state, *,
     with span("router.select.readback"):
         # One device_get starts all five copies before waiting on any.
         picks, admitted, has_base, rep, w_chosen = jax.device_get(
-            fn(*args, r01, lim_dev))
+            fn(pool.mu, pool.sigma, pool.acc, pool.rank, packed,
+               n_replicas=R, bpad=bpad))
         return (picks[:B], admitted[:B], has_base[:B], rep[:B],
                 np.asarray(w_chosen, np.float64)[:B])
 

@@ -45,8 +45,7 @@ MODEL_SCOPES = (
 ROUTER_SPANS = (
     "router.route_batch",       # the whole batch call
     "router.charged_loop",      # the host's sequential charged loop
-    "router.select.pack",       # ledger columns, padding, host to device
-    "router.select.draw",       # the batch's uniform draws
+    "router.select.pack",       # ledger columns and key, one host to device
     "router.select.readback",   # the charged scan and the read of its columns
     "router.apply",             # decisions written back row by row
 )

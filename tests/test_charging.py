@@ -593,8 +593,9 @@ def _rowwise_cand_mask(npad, R, cand):
 def test_charged_pack_operands(monkeypatch, topology):
     """The vectorised pack builds the per-model loop's ``cand_mask`` (a
     replica serving two models; the zoo's 11 models × 16 replicas), and
-    the jitted ``run`` receives the operands it received before the
-    one-transfer upload: same order, shapes, dtypes and values."""
+    the jitted ``run`` receives the pool columns and one packed buffer
+    that unpacks to the operands the pass received as separate arrays:
+    same order, shapes, dtypes and values, then ``PRNGKey(seed)``."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import policy_select
@@ -623,33 +624,114 @@ def test_charged_pack_operands(monkeypatch, topology):
     lim = np.full(bpad, -np.inf, np.float32)
     lim[:B] = np.asarray(budgets, np.float32)
     f32 = jnp.float32
-    want = (pool.mu, pool.sigma, pool.acc, pool.rank,
-            jnp.asarray(mu_charge),
+    want = (jnp.asarray(mu_charge),
             jnp.asarray(_rowwise_cand_mask(pool.npad, R, state.cand)),
             jnp.asarray(state.speed, f32), jnp.asarray(state.rep_wait, f32),
             jnp.asarray(policy_select._pad_batch(budgets, bpad)),
-            jnp.asarray(policy_select._pad_batch(t_l, bpad)))
+            jnp.asarray(policy_select._pad_batch(t_l, bpad)),
+            jnp.asarray(lim))
     seen = []
     real_jit = policy_select._charged_jit
 
     def spy(*key):
         fn = real_jit(*key)
 
-        def run(*args):
-            seen.append(args)
-            return fn(*args)
+        def run(*args, **kw):
+            seen.append((args, kw))
+            return fn(*args, **kw)
         return run
     monkeypatch.setattr(policy_select, "_charged_jit", spy)
     policy_select.charged_select(pool, budgets, t_l, state, seed=3,
                                  adm_limit=budgets)
-    (args,) = seen
-    assert len(args) == len(want) + 2
-    r01 = args[len(want)]
-    assert (r01.shape, r01.dtype) == ((bpad,), f32)
-    for i, (got, exp) in enumerate(zip(args, want + (r01,
-                                                       jnp.asarray(lim)))):
-        assert isinstance(got, jax.Array), i
-        assert (got.shape, got.dtype, got.weak_type) == (
+    ((args, kw),) = seen
+    assert kw == {"n_replicas": R, "bpad": bpad}
+    assert len(args) == 5
+    for got, col in zip(args, (pool.mu, pool.sigma, pool.acc, pool.rank)):
+        assert got is col
+    packed = args[4]
+    assert isinstance(packed, jax.Array)
+    size = pool.npad * (R + 1) + 2 * R + 3 * bpad + 2
+    assert (packed.shape, packed.dtype) == ((size,), jnp.uint32)
+    *got, key = policy_select._charged_unpack(packed, pool.npad, R, bpad)
+    for i, (g, exp) in enumerate(zip(got, want)):
+        assert (g.shape, g.dtype, g.weak_type) == (
             exp.shape, exp.dtype, exp.weak_type), i
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp),
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(exp),
                                       err_msg=str(i))
+    np.testing.assert_array_equal(
+        np.asarray(key), np.asarray(jax.random.key_data(
+            jax.random.PRNGKey(3))))
+
+
+def test_charged_select_one_transfer(monkeypatch):
+    """A charged tick makes no implicit host→device transfer and exactly
+    one explicit one, of one array: the packed operands and key words."""
+    import jax
+    from repro.kernels import policy_select
+    tab, state, budgets = _zoo_charged(300, 16, False, seed=5)
+    pool = tab.device_pool()
+    args = (pool, budgets, budgets - 20.0, state)
+    kw = dict(seed=2 ** 63 - 1, adm_limit=budgets)
+    policy_select.charged_select(*args, **kw)       # compile outside
+    puts = []
+    real_put = jax.device_put
+
+    def spy(x, *a, **k):
+        puts.append(x)
+        return real_put(x, *a, **k)
+    monkeypatch.setattr(jax, "device_put", spy)
+    with jax.transfer_guard_host_to_device("disallow"):
+        out = policy_select.charged_select(*args, **kw)
+    (put,) = puts
+    assert isinstance(put, np.ndarray) and put.dtype == np.uint32
+    assert out[1].any()
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 32 + 5, 8765432109876543210,
+                                  2 ** 63 - 1, -5])
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+def test_charged_key_words(seed, x64):
+    """The host-made key words are ``PRNGKey(seed)``'s in either mode."""
+    import jax
+    from repro.kernels import policy_select
+    with jax.enable_x64(x64):
+        want = np.asarray(jax.random.key_data(jax.random.PRNGKey(seed)))
+        np.testing.assert_array_equal(policy_select._key_words(seed), want)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["scan", "kernel"])
+@pytest.mark.parametrize("B", [4096, 1200])
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 32 + 5, 2 ** 63 - 1])
+def test_charged_draw_in_run_matches_eager_draw(monkeypatch, seed, B,
+                                                kernel):
+    """The uniforms drawn inside ``run`` from the packed key words are the
+    eager ``uniform(PRNGKey(seed))`` stream: the five columns equal those
+    of the same pass fed that eager draw, on the scan and on the
+    (interpreted) kernel, at a full tick and at one whose rows are not a
+    multiple of the kernel's row block."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import policy_select
+    tab, state, budgets = _zoo_charged(B, 16, False, seed=B)
+    pool = tab.device_pool()
+    t_l = budgets - 20.0
+    monkeypatch.setattr(policy_select, "charged_kernel_engaged",
+                        lambda npad, n_replicas: kernel)
+    got = policy_select.charged_select(pool, budgets, t_l, state,
+                                       seed=seed, adm_limit=budgets)
+
+    bpad = policy_select._bucket(B, 256)
+    host = policy_select._charged_operands(pool.npad, pool.n, bpad,
+                                           budgets, t_l, state, budgets)
+    r01 = jax.random.uniform(jax.random.PRNGKey(seed), (bpad,),
+                             dtype=jnp.float32)
+    ref = jax.jit(functools.partial(
+        policy_select._charged_pass, gamma=1.0, slack=0.0,
+        include_mu=False, fastest=pool.fastest, use_kernel=kernel,
+        interpret=kernel))(pool.mu, pool.sigma, pool.acc, pool.rank,
+                           *host[:6], r01, host[6])
+    for name, g, want in zip(("picks", "admitted", "has_base", "replica",
+                              "w_chosen"), got, jax.device_get(ref)):
+        np.testing.assert_array_equal(g, np.asarray(want)[:B],
+                                      err_msg=name)

@@ -77,8 +77,8 @@ def zoo_tick(backend, B=256):
 
 
 @pytest.mark.parametrize("backend, phases", [
-    ("jax", ("router.select.pack", "router.select.draw",
-             "router.select.readback", "router.apply")),
+    ("jax", ("router.select.pack", "router.select.readback",
+             "router.apply")),
     ("numpy", ("router.charged_loop",)),
 ])
 def test_router_tick_spans_nest(tmp_path, backend, phases):

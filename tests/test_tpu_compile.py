@@ -69,16 +69,19 @@ def test_fused_selection_compiles_with_pallas_kernel(one_chip, batch):
     assert compiled.as_text()
 
 
+def _lower_charged(fn, sharding, lanes, replicas, batch):
+    """The charged program on the pool columns and one packed buffer."""
+    vec = _sds((lanes,), jnp.float32, sharding)
+    size = policy_select._charged_layout(lanes, replicas, batch)[-1][1]
+    return fn.lower(vec, vec, vec, vec, _sds((size,), jnp.uint32, sharding),
+                    n_replicas=replicas, bpad=batch)
+
+
 def test_charged_select_compiles(one_chip):
     lanes, replicas, batch = policy_select.LANES, 11, 4096
     fn = policy_select._charged_jit(lanes, 1.0, 0.0, False, 0)
-    f32 = jnp.float32
-    vec = _sds((lanes,), f32, one_chip)
-    rep = _sds((replicas,), f32, one_chip)
-    req = _sds((batch,), f32, one_chip)
-    compiled = fn.lower(vec, vec, vec, vec, vec,
-                        _sds((lanes, replicas), jnp.bool_, one_chip),
-                        rep, rep, req, req, req, req).compile()
+    compiled = _lower_charged(fn, one_chip, lanes, replicas,
+                              batch).compile()
     assert compiled.as_text()
 
 
@@ -89,13 +92,8 @@ def test_charged_select_kernel_compiles(one_chip, batch):
     are not a multiple of the kernel's row block."""
     lanes, replicas = policy_select.LANES, 176
     fn = policy_select._charged_jit(lanes, 1.0, 0.0, True, 1, True)
-    f32 = jnp.float32
-    vec = _sds((lanes,), f32, one_chip)
-    rep = _sds((replicas,), f32, one_chip)
-    req = _sds((batch,), f32, one_chip)
-    compiled = fn.lower(vec, vec, vec, vec, vec,
-                        _sds((lanes, replicas), jnp.bool_, one_chip),
-                        rep, rep, req, req, req, req).compile()
+    compiled = _lower_charged(fn, one_chip, lanes, replicas,
+                              batch).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
